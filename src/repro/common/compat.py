@@ -1,58 +1,23 @@
-"""Version-compatibility shims for the pinned jax (0.4.37).
+"""The one place that builds meshes.
 
-Newer-jax APIs the codebase wants but the pin lacks live here, in one
-place, so the guards don't drift apart across modules:
-
-  AxisType      — jax.sharding.AxisType (>= 0.5), else None
-  make_mesh     — jax.make_mesh with Auto axis_types when supported
-  mesh_from_devices — explicit-device Mesh with the same axis_types rule
-  shard_map     — jax.shard_map (>= 0.6) or jax.experimental.shard_map,
-                  with the replication-check kwarg normalized away
-  axis_size     — jax.lax.axis_size (>= 0.5) or the psum(1, axis) idiom
+``jax.make_mesh`` defaults to ``Explicit`` axis types, and under those
+``with_sharding_constraint`` refuses the PartitionSpecs the sharding
+rules emit ("can only refer to Auto axes of the mesh").  Every mesh in
+the code and the tests is built here, with ``Auto`` axis types.
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:                                   # jax >= 0.5 only; 0.4.x lacks it
-    from jax.sharding import AxisType
-except ImportError:                    # pragma: no cover - version dependent
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """Mesh of ``shape`` over all devices of the process."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_from_devices(devices, axes: Tuple[str, ...]) -> Mesh:
     """Mesh over an explicit [*shape]-shaped device array."""
-    if AxisType is not None:
-        return Mesh(devices, axes,
-                    axis_types=(AxisType.Auto,) * len(axes))
-    return Mesh(devices, axes)
-
-
-if hasattr(jax, "shard_map"):          # jax >= 0.6
-    _new_shard_map = jax.shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-else:                                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
-
-def axis_size(name: str) -> int:
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.5
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)       # constant-folds to the size
+    return Mesh(devices, axes, axis_types=(AxisType.Auto,) * len(axes))

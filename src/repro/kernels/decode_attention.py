@@ -1,22 +1,23 @@
-"""Flash-decode as Pallas TPU kernels: one query token per sequence
+"""Flash-decode as a Pallas TPU kernel: one query token per sequence
 against a long KV cache, GQA-aware (KV read once per KV head, applied to
 all G query heads in the group).
 
-Two layouts:
+Two layouts, one kernel:
 
-  decode_attention        — contiguous per-slot cache [B, S, KH, hd].
   paged_decode_attention  — block-pool cache [N, bs, KH, hd] indexed
       through a per-sequence block table (vLLM-style).  The table and the
       valid lengths ride in as *scalar-prefetch* operands, so the block
       index maps can compute DMA sources from the table before the kernel
       body runs — the gather costs no extra pass over HBM.
+  decode_attention        — contiguous per-slot cache [B, S, KH, hd],
+      served as a pool with the identity table.
 
-Both iterate the cache-sequence dim sequentially (online softmax in VMEM
-scratch) with a grid of (B, KH, n_s).  Per-slot valid lengths mask ragged
+The grid is (B, n_s): the cache-sequence dim is iterated sequentially
+(online softmax in VMEM scratch).  Per-slot valid lengths mask ragged
 continuous-batching batches, and ``max_len`` (the max *valid* length in
 the batch, known on the host) truncates the sequential grid so a short
 batch does not sweep empty cache blocks — decode is bandwidth-bound and
-these kernels read each *live* cache byte exactly once.
+the kernel reads each *live* cache byte exactly once.
 """
 from __future__ import annotations
 
@@ -57,108 +58,16 @@ def _online_softmax_step(q, k, v, s_start, length, m_scr, l_scr, acc_scr, *,
         p, v, (((1,), (0,)), ((), ())))
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, *rest,
-            scale: float, block_s: int, n_s: int):
-    if len(rest) == 6:          # int8 cache: scale blocks ride along
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-        ks_ref = vs_ref = None
-    si = pl.program_id(2)
-
-    @pl.when(si == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[0]
-    s_start = si * block_s
-
-    @pl.when(s_start < length)
-    def _compute():
-        _online_softmax_step(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
-                             s_start, length, m_scr, l_scr, acc_scr,
-                             scale=scale,
-                             ks=None if ks_ref is None else ks_ref[0, 0],
-                             vs=None if vs_ref is None else vs_ref[0, 0])
-
-    @pl.when(si == n_s - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[...], 1e-37)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     lengths: jax.Array, *, block_s: int = 512,
-                     max_len: Optional[int] = None,
-                     k_scale: Optional[jax.Array] = None,
-                     v_scale: Optional[jax.Array] = None,
-                     interpret: bool = True) -> jax.Array:
-    """q: [B, H, hd]; caches: [B, S, KH, hd]; lengths: [B] valid rows.
-    ``max_len`` (static, host-known upper bound on lengths) truncates the
-    sequential sweep to the live prefix of the cache.  int8 caches pass
-    ``k_scale``/``v_scale`` [B, S, KH, 1] per-token-per-head scales;
-    dequant is fused into the online-softmax loop.  Returns [B, H, hd].
-    """
-    B, S, KH, hd = k_cache.shape
-    H = q.shape[1]
-    dv = v_cache.shape[-1]
-    G = H // KH
-    block_s = min(block_s, S)
-    if S % block_s:
-        raise ValueError(f"cache len {S} must tile {block_s}")
-    n_s = S // block_s
-    if max_len is not None:
-        n_s = max(1, min(n_s, -(-max_len // block_s)))
-    qr = q.reshape(B, KH, G, hd)
-    kr = k_cache.transpose(0, 2, 1, 3)                    # [B, KH, S, hd]
-    vr = v_cache.transpose(0, 2, 1, 3)
-
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, n, s: (b,)),
-        pl.BlockSpec((1, 1, G, hd), lambda b, n, s: (b, n, 0, 0)),
-        pl.BlockSpec((1, 1, block_s, hd), lambda b, n, s: (b, n, s, 0)),
-        pl.BlockSpec((1, 1, block_s, dv), lambda b, n, s: (b, n, s, 0)),
-    ]
-    inputs = [lengths.astype(jnp.int32), qr, kr, vr]
-    if k_scale is not None:
-        in_specs += [pl.BlockSpec((1, 1, block_s, 1),
-                                  lambda b, n, s: (b, n, s, 0))] * 2
-        inputs += [k_scale.transpose(0, 2, 1, 3).astype(jnp.float32),
-                   v_scale.transpose(0, 2, 1, 3).astype(jnp.float32)]
-
-    kernel = functools.partial(_kernel, scale=hd ** -0.5,
-                               block_s=block_s, n_s=n_s)
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, KH, n_s),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, dv), lambda b, n, s: (b, n, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*inputs)
-    return out.reshape(B, H, dv)
-
-
-# ---------------------------------------------------------------------------
-# paged layout
-# ---------------------------------------------------------------------------
-
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  scale: float, block_s: int, n_s: int):
+                  scale: float, block_s: int, n_s: int, kv_heads: int,
+                  hd: int, dv: int):
     if len(rest) == 6:          # int8 pools: scale blocks ride along
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
         ks_ref = vs_ref = None
     b = pl.program_id(0)
-    si = pl.program_id(2)
+    si = pl.program_id(1)
 
     @pl.when(si == 0)
     def _init():
@@ -171,17 +80,20 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(s_start < length)
     def _compute():
-        # k/v blocks were DMA'd from pool row tbl[b, si] by the index map
-        _online_softmax_step(q_ref[0, 0], k_ref[0, :, 0], v_ref[0, :, 0],
-                             s_start, length, m_scr, l_scr, acc_scr,
-                             scale=scale,
-                             ks=None if ks_ref is None else ks_ref[0, :, 0],
-                             vs=None if vs_ref is None else vs_ref[0, :, 0])
+        # the k/v block (all KV heads, lanes = head-major [KH·hd]) was
+        # DMA'd from pool row tbl[b, si] by the index map
+        for n in range(kv_heads):
+            _online_softmax_step(
+                q_ref[0, n], k_ref[0, :, n * hd:(n + 1) * hd],
+                v_ref[0, :, n * dv:(n + 1) * dv], s_start, length,
+                m_scr.at[n], l_scr.at[n], acc_scr.at[n], scale=scale,
+                ks=None if ks_ref is None else ks_ref[0, :, n:n + 1],
+                vs=None if vs_ref is None else vs_ref[0, :, n:n + 1])
 
     @pl.when(si == n_s - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-37)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -190,7 +102,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            max_len: Optional[int] = None,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Flash-decode over a block-pool cache.
 
     q: [B, H, hd]; pools: [N, block_size, KH, hd]; block_table:
@@ -203,9 +115,12 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     The table and lengths are scalar-prefetch operands: the k/v BlockSpec
     index maps dereference ``tbl[b, si]`` to pick the DMA source block, so
     the kernel streams exactly the blocks the table names — the paged
-    gather is free.  int8 pools pass ``k_scale``/``v_scale``
-    [N, block_size, KH, 1] scale pools, whose blocks ride the same
-    table-driven index maps; dequant is fused into the softmax loop.
+    gather is free.  Each grid step takes one whole pool block, all KV
+    heads at once, viewed as [block_size, KH·hd]: the block's last two
+    dims then span the array's, which the TPU's tiling rules require for
+    any KH.  int8 pools pass ``k_scale``/``v_scale`` [N, block_size, KH,
+    1] scale pools, whose blocks ride the same table-driven index maps;
+    dequant is fused into the softmax loop.
     """
     N, bs, KH, hd = k_pool.shape
     B, H = q.shape[:2]
@@ -215,35 +130,36 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     n_s = nmax
     if max_len is not None:
         n_s = max(1, min(nmax, -(-max_len // bs)))
-    qr = q.reshape(B, KH, G, hd)
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, bs, width),
+                            lambda b, s, tbl, lens: (tbl[b, s], 0, 0))
 
     in_specs = [
-        pl.BlockSpec((1, 1, G, hd),
-                     lambda b, n, s, tbl, lens: (b, n, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd),
-                     lambda b, n, s, tbl, lens: (tbl[b, s], 0, n, 0)),
-        pl.BlockSpec((1, bs, 1, dv),
-                     lambda b, n, s, tbl, lens: (tbl[b, s], 0, n, 0)),
+        pl.BlockSpec((1, KH, G, hd), lambda b, s, tbl, lens: (b, 0, 0, 0)),
+        kv_spec(KH * hd),
+        kv_spec(KH * dv),
     ]
-    inputs = [qr, k_pool, v_pool]
+    inputs = [q.reshape(B, KH, G, hd), k_pool.reshape(N, bs, KH * hd),
+              v_pool.reshape(N, bs, KH * dv)]
     if k_scale is not None:
-        in_specs += [pl.BlockSpec((1, bs, 1, 1),
-                                  lambda b, n, s, tbl, lens:
-                                  (tbl[b, s], 0, n, 0))] * 2
-        inputs += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        in_specs += [kv_spec(KH)] * 2
+        inputs += [k_scale.reshape(N, bs, KH).astype(jnp.float32),
+                   v_scale.reshape(N, bs, KH).astype(jnp.float32)]
 
     kernel = functools.partial(_paged_kernel, scale=hd ** -0.5,
-                               block_s=bs, n_s=n_s)
+                               block_s=bs, n_s=n_s, kv_heads=KH, hd=hd,
+                               dv=dv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KH, n_s),
+        grid=(B, n_s),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, dv),
-                               lambda b, n, s, tbl, lens: (b, n, 0, 0)),
+        out_specs=pl.BlockSpec((1, KH, G, dv),
+                               lambda b, s, tbl, lens: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, dv), jnp.float32),
+            pltpu.VMEM((KH, G, 1), jnp.float32),
+            pltpu.VMEM((KH, G, 1), jnp.float32),
+            pltpu.VMEM((KH, G, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -254,3 +170,37 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       *inputs)
     return out.reshape(B, H, dv)
+
+
+def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     lengths: jax.Array, *, block_s: int = 512,
+                     max_len: Optional[int] = None,
+                     k_scale: Optional[jax.Array] = None,
+                     v_scale: Optional[jax.Array] = None,
+                     interpret: bool) -> jax.Array:
+    """q: [B, H, hd]; caches: [B, S, KH, hd]; lengths: [B] valid rows.
+    ``max_len`` (static, host-known upper bound on lengths) truncates the
+    sequential sweep to the live prefix of the cache.  int8 caches pass
+    ``k_scale``/``v_scale`` [B, S, KH, 1] per-token-per-head scales;
+    dequant is fused into the online-softmax loop.  Returns [B, H, hd].
+
+    A contiguous cache is a block pool whose table is the identity: each
+    slot's ``S / block_s`` blocks are consecutive pool rows, so the paged
+    kernel serves it through a free reshape.
+    """
+    B, S, KH, hd = k_cache.shape
+    block_s = min(block_s, S)
+    if S % block_s:
+        raise ValueError(f"cache len {S} must tile {block_s}")
+    n_s = S // block_s
+
+    def pool(x):
+        return None if x is None else x.reshape(B * n_s, block_s,
+                                                 *x.shape[2:])
+
+    table = jnp.arange(B * n_s, dtype=jnp.int32).reshape(B, n_s)
+    return paged_decode_attention(q, pool(k_cache), pool(v_cache), table,
+                                  lengths, max_len=max_len,
+                                  k_scale=pool(k_scale),
+                                  v_scale=pool(v_scale),
+                                  interpret=interpret)

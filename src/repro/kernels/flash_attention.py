@@ -80,7 +80,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     softcap: Optional[float] = None,
                     block_q: int = 256, block_k: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: [B, Sq, H, hd]; k/v: [B, Sk, H, hd/dv] (kv pre-expanded to H
     heads).  Returns [B, Sq, H, dv]."""
     B, Sq, H, hd = q.shape

@@ -1,13 +1,14 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Jit'd public entries for the Pallas kernels.
 
-``INTERPRET`` is True in this container (CPU: the kernel bodies execute
-as pure JAX for correctness validation); on a real TPU it flips to False
-and the same call sites compile to Mosaic kernels.
+Each call picks the kernel's mode from the platform it runs on: on a TPU
+the kernel compiles to a Mosaic custom call; on any other backend (the
+CPU tests) its body runs in the Pallas interpreter.  The raw entries in
+the kernel modules have no default for ``interpret``: every caller
+states the mode.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 
@@ -17,52 +18,23 @@ from repro.kernels import quant_matmul as _qm
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssm_scan as _ss
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _entry(kernel, *static_argnames):
+    jitted = jax.jit(kernel,
+                     static_argnames=static_argnames + ("interpret",))
+
+    @functools.wraps(kernel)
+    def call(*args, **kwargs):
+        return jitted(*args, interpret=jax.default_backend() != "tpu",
+                      **kwargs)
+
+    return call
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "softcap",
-                                             "block_q", "block_k"))
-def flash_attention(q, k, v, *, causal: bool = True,
-                    softcap: Optional[float] = None,
-                    block_q: int = 256, block_k: int = 256):
-    return _fa.flash_attention(q, k, v, causal=causal, softcap=softcap,
-                               block_q=block_q, block_k=block_k,
-                               interpret=INTERPRET)
-
-
-@functools.partial(jax.jit, static_argnames=("block_s", "max_len"))
-def decode_attention(q, k_cache, v_cache, lengths, *, block_s: int = 512,
-                     max_len: Optional[int] = None,
-                     k_scale=None, v_scale=None):
-    return _da.decode_attention(q, k_cache, v_cache, lengths,
-                                block_s=block_s, max_len=max_len,
-                                k_scale=k_scale, v_scale=v_scale,
-                                interpret=INTERPRET)
-
-
-@functools.partial(jax.jit, static_argnames=("max_len",))
-def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
-                           max_len: Optional[int] = None,
-                           k_scale=None, v_scale=None):
-    return _da.paged_decode_attention(q, k_pool, v_pool, block_table,
-                                      lengths, max_len=max_len,
-                                      k_scale=k_scale, v_scale=v_scale,
-                                      interpret=INTERPRET)
-
-
-@functools.partial(jax.jit, static_argnames=("block_m", "block_n"))
-def int8_matmul(x, w, scale, *, block_m: int = 256, block_n: int = 256):
-    return _qm.int8_matmul(x, w, scale, block_m=block_m, block_n=block_n,
-                           interpret=INTERPRET)
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "block_d"))
-def ssm_scan(a, b, h0, *, chunk: int = 256, block_d: int = 0):
-    return _ss.ssm_scan(a, b, h0, chunk=chunk, block_d=block_d,
-                        interpret=INTERPRET)
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "block_rows"))
-def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256):
-    return _rn.rmsnorm(x, scale, eps=eps, block_rows=block_rows,
-                       interpret=INTERPRET)
+flash_attention = _entry(_fa.flash_attention, "causal", "softcap",
+                         "block_q", "block_k")
+decode_attention = _entry(_da.decode_attention, "block_s", "max_len")
+paged_decode_attention = _entry(_da.paged_decode_attention, "max_len")
+int8_matmul = _entry(_qm.int8_matmul, "block_m", "block_n")
+ssm_scan = _entry(_ss.ssm_scan, "chunk", "block_d")
+rmsnorm = _entry(_rn.rmsnorm, "eps", "block_rows")

@@ -15,12 +15,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _tile(dim: int, pref: int) -> int:
-    """Largest divisor of ``dim`` that is <= ``pref``."""
-    t = min(dim, pref)
-    while dim % t:
-        t -= 1
-    return t
+def _tile(dim: int, pref: int, align: int) -> int:
+    """Largest divisor of ``dim`` that is <= ``pref`` and a multiple of
+    ``align`` (the TPU tiling of that block dim), else ``dim`` itself —
+    a block spanning the whole dim is always legal."""
+    for t in range(min(dim, pref) // align * align, 0, -align):
+        if dim % t == 0:
+            return t
+    return dim
 
 
 def _kernel(x_ref, w_ref, s_ref, o_ref):
@@ -33,7 +35,7 @@ def _kernel(x_ref, w_ref, s_ref, o_ref):
 
 def int8_matmul(x: jax.Array, w: jax.Array, scale: jax.Array, *,
                 block_m: int = 256, block_n: int = 256,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """x: [M, K] float; w: [K, N] int8; scale: [1, N] fp32 per-output-
     channel.  Returns [M, N] fp32 = (x @ dequant(w)) with the rescale
     fused into the accumulator."""
@@ -41,7 +43,7 @@ def int8_matmul(x: jax.Array, w: jax.Array, scale: jax.Array, *,
     Kw, N = w.shape
     if K != Kw:
         raise ValueError(f"contraction mismatch: {x.shape} @ {w.shape}")
-    bm, bn = _tile(M, block_m), _tile(N, block_n)
+    bm, bn = _tile(M, block_m, 8), _tile(N, block_n, 128)
     return pl.pallas_call(
         _kernel,
         grid=(M // bm, N // bn),

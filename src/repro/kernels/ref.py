@@ -56,6 +56,12 @@ def paged_decode_attention_ref(q, k_pool, v_pool, block_table,
     return decode_attention_ref(q, k, v, lengths)
 
 
+def int8_matmul_ref(x, w, scale) -> jax.Array:
+    """x: [M, K] float; w: [K, N] int8; scale: [1, N] per-output-channel.
+    Returns [M, N] fp32 = x @ (w * scale)."""
+    return x.astype(jnp.float32) @ (w.astype(jnp.float32) * scale)
+
+
 def ssm_scan_ref(a, b, h0) -> tuple:
     """h_t = a_t * h_{t-1} + b_t.  a/b: [B, S, ...]; h0: [B, ...].
     Returns (h [B, S, ...], h_last [B, ...]) in fp32."""

@@ -18,7 +18,7 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool = True) -> jax.Array:
+            block_rows: int = 256, interpret: bool) -> jax.Array:
     """x: [..., d]; scale: [d] (gemma-style 1+scale)."""
     shp = x.shape
     d = shp[-1]

@@ -9,19 +9,22 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional, Sequence
 
 import jax
 import numpy as np
 
 from repro.checkpoint import store as ckpt_lib
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.launch import steps as steps_lib
-from repro.serving.engine import Engine, EngineStallError, RequestState
+from repro.serving.engine import (Engine, EngineStallError, Request,
+                                  RequestState)
 from repro.serving.faults import FaultPlan
 from repro.serving.sampler import SampleParams
 
 
-def main() -> None:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -107,16 +110,19 @@ def main() -> None:
     ap.add_argument("--fault-max", type=int, default=None,
                     help="cap on total injected faults (a storm that "
                     "clears; default unbounded)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
+
+def init_params(cfg, seed: int):
+    """Random-init weights on the default device, in one compiled
+    program (no host copy, no per-op dispatch at full width)."""
     fns = steps_lib.model_fns(cfg)
-    params = fns["init"](jax.random.PRNGKey(args.seed), cfg)
-    if args.ckpt_dir:
-        state = ckpt_lib.restore(args.ckpt_dir, {"params": params})
-        params = state["params"]
-        print(f"[serve] loaded params from {args.ckpt_dir}")
+    return jax.jit(lambda key: fns["init"](key, cfg))(
+        jax.random.PRNGKey(seed))
 
+
+def build_engine(args: argparse.Namespace, cfg, params) -> Engine:
+    """The engine the launcher's options describe."""
     plan = None
     if args.fault_alloc_p or args.fault_transfer_p or args.fault_slow_p:
         plan = FaultPlan(seed=args.fault_seed, alloc_p=args.fault_alloc_p,
@@ -127,22 +133,56 @@ def main() -> None:
               f"alloc_p={plan.alloc_p} transfer_p={plan.transfer_p} "
               f"slow_p={plan.slow_p} max={plan.max_faults}")
     max_seq = args.shared_prefix + args.input_len + args.output_len + 8
-    eng = Engine(cfg, params, max_slots=args.slots, max_seq_len=max_seq,
-                 max_waiting_prefill_tokens=args.prefill_budget,
-                 paged=not args.contiguous, block_size=args.block_size,
-                 num_blocks=args.num_blocks,
-                 prefill_chunk=args.prefill_chunk,
-                 speculate_k=args.speculate_k,
-                 draft_tracks=args.draft_tracks,
-                 prefix_cache=not args.no_prefix_cache,
-                 kv_dtype=args.kv_dtype,
-                 weight_dtype=args.weight_dtype,
-                 max_queue=args.max_queue,
-                 watchdog_patience=args.watchdog_patience,
-                 max_preemptions=args.max_preemptions,
-                 fault_plan=plan,
-                 pipeline_depth=args.pipeline_depth,
-                 preplan=args.preplan)
+    return Engine(cfg, params, max_slots=args.slots, max_seq_len=max_seq,
+                  max_waiting_prefill_tokens=args.prefill_budget,
+                  paged=not args.contiguous, block_size=args.block_size,
+                  num_blocks=args.num_blocks,
+                  prefill_chunk=args.prefill_chunk,
+                  speculate_k=args.speculate_k,
+                  draft_tracks=args.draft_tracks,
+                  prefix_cache=not args.no_prefix_cache,
+                  kv_dtype=args.kv_dtype,
+                  weight_dtype=args.weight_dtype,
+                  max_queue=args.max_queue,
+                  watchdog_patience=args.watchdog_patience,
+                  max_preemptions=args.max_preemptions,
+                  fault_plan=plan,
+                  pipeline_depth=args.pipeline_depth,
+                  preplan=args.preplan)
+
+
+def submit_workload(eng: Engine, args: argparse.Namespace, cfg,
+                    seed: int) -> List[Request]:
+    """Submit ``args.requests`` prompts of random tokens drawn from
+    ``seed``: a shared prefix of ``args.shared_prefix`` tokens, then
+    ``args.input_len`` tokens of each request's own."""
+    rng = np.random.default_rng(seed)
+    sp = SampleParams(temperature=args.temperature)
+    shared = rng.integers(1, cfg.vocab_size,
+                          size=(args.shared_prefix,)).tolist()
+    reqs = []
+    for i in range(args.requests):
+        prompt = shared + rng.integers(1, cfg.vocab_size,
+                                       size=(args.input_len,)).tolist()
+        reqs.append(eng.submit(prompt, args.output_len, params=sp,
+                               priority=i % max(1, args.priority_mix),
+                               deadline_s=args.deadline_s))
+    return reqs
+
+
+def main() -> None:
+    args = parse_args()
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[serve] device: {dev.platform} {dev.device_kind} "
+          f"x{jax.device_count()}")
+    cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
+    params = init_params(cfg, args.seed)
+    if args.ckpt_dir:
+        state = ckpt_lib.restore(args.ckpt_dir, {"params": params})
+        params = state["params"]
+        print(f"[serve] loaded params from {args.ckpt_dir}")
+    eng = build_engine(args, cfg, params)
     if args.preplan:
         print(f"[serve] pre-planned {eng.runner.plan_programs()} "
               f"per-bucket step programs")
@@ -172,19 +212,8 @@ def main() -> None:
         print(f"[serve] quantized: kv={st.get('kv_dtype', 'float32')} "
               f"weights={st['weight_dtype']} "
               f"({st['quantized_weight_leaves']} leaves){extra}")
-    rng = np.random.default_rng(args.seed)
-    sp = SampleParams(temperature=args.temperature)
-    shared = rng.integers(1, cfg.vocab_size,
-                          size=(args.shared_prefix,)).tolist()
-
     t0 = time.perf_counter()
-    reqs = []
-    for i in range(args.requests):
-        prompt = shared + rng.integers(1, cfg.vocab_size,
-                                       size=(args.input_len,)).tolist()
-        reqs.append(eng.submit(prompt, args.output_len, params=sp,
-                               priority=i % max(1, args.priority_mix),
-                               deadline_s=args.deadline_s))
+    reqs = submit_workload(eng, args, cfg, args.seed)
     try:
         eng.run()
     except EngineStallError as e:
@@ -233,8 +262,8 @@ def main() -> None:
               f"shed {m['shed']}, rejected {m['rejected']}, "
               f"timed_out {m['timed_out']}, watchdog {m['watchdog_fires']}, "
               f"transfer_faults {m['transfer_faults']}")
-    if plan is not None:
-        fs = plan.summary()
+    if eng.faults is not None:
+        fs = eng.faults.summary()
         print(f"[serve] faults injected: {fs['injected']} "
               f"(alloc {fs['alloc_faults']}, transfer "
               f"{fs['transfer_faults']}, slow {fs['slow_steps']})")

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import store as ckpt_lib
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.pytree import count_params
 from repro.configs import get_config, reduced_config
 from repro.data.pipeline import DataConfig, DataLoader
@@ -118,6 +119,7 @@ def main() -> None:
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--max-restarts", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     mesh = None

@@ -38,9 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import axis_size as _axis_size
-from repro.common.compat import shard_map as _shard_map
-
 from repro.common.types import ModelConfig
 from repro.runtime.parallel import Parallelism, NO_PARALLEL
 
@@ -145,7 +142,7 @@ def _moe_block(x2, params, *, cfg: ModelConfig, cap: int,
     T_loc, d = x2.shape
     E_total = e_store(cfg)
 
-    tp = _axis_size(tp_axis) if tp_axis else 1
+    tp = jax.lax.axis_size(tp_axis) if tp_axis else 1
     T_sub = -(-T_loc // tp)
     if tp > 1:
         x_pad = jnp.pad(x2, ((0, T_sub * tp - T_loc), (0, 0)))
@@ -160,7 +157,7 @@ def _moe_block(x2, params, *, cfg: ModelConfig, cap: int,
 
     ep = 1
     for a in ep_axes:
-        ep *= _axis_size(a)
+        ep *= jax.lax.axis_size(a)
     if ep > 1:
         buf = jax.lax.all_to_all(buf, ep_axes, split_axis=0, concat_axis=0,
                                  tiled=True)
@@ -262,10 +259,10 @@ def moe_apply(params, x: jax.Array, *, cfg: ModelConfig,
     in_x = P(b_shard if len(b_shard) > 1 else (b_shard[0] if b_shard else None),
              None, None)
     pspecs = _param_specs(params, cfg, ep_axes, tp_axis)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(in_x, pspecs),
-        out_specs=(in_x, P()))(x, params)
+        out_specs=(in_x, P()), check_vma=False)(x, params)
     return y, aux
 
 

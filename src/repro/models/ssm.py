@@ -169,8 +169,8 @@ def ssm_chunk(params, x: jax.Array, cache, *, cfg: ModelConfig,
         b = jnp.where(valid[..., None, None], b, 0.0)
     h0 = h0.astype(jnp.float32)
     if cfg.use_pallas and par.mesh is None and C % min(s.chunk, C) == 0:
-        from repro.kernels.ssm_scan import ssm_scan
-        h, h_last = ssm_scan(a, b, h0, chunk=s.chunk)
+        from repro.kernels import ops as kops
+        h, h_last = kops.ssm_scan(a, b, h0, chunk=s.chunk)
     else:
         h, h_last = _chunked_linear_scan(a, b, h0, s.chunk)
     y = jnp.einsum("bsiz,bsz->bsi", h, Ct.astype(jnp.float32))

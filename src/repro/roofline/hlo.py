@@ -44,10 +44,11 @@ _TRAFFIC_OPS = _COLLECTIVES + (
 )
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-# type is either a parenthesized tuple (may contain /*index=N*/ comments,
-# never nested parens) or a single space-free token like bf16[8,16]{1,0}
+# type is either a parenthesized tuple (may contain /*index=N*/ comments
+# and, in TPU layouts, one level of parens such as {1,0:T(8,128)(2,1)}) or
+# a single space-free token like bf16[8,16]{1,0}
 _OP_LINE = re.compile(
-    r"^(?:ROOT\s+)?%([\w\.\-]+)\s*=\s*(\([^)]*\)|\S+)\s+"
+    r"^(?:ROOT\s+)?%([\w\.\-]+)\s*=\s*(\((?:[^()]|\([^()]*\))*\)|\S+)\s+"
     r"([\w\-]+)\((.*)$")
 _COMP_HEADER = re.compile(r"^(?:ENTRY )?%([\w\.\-]+)\s*\(")
 _WHILE_RE = re.compile(
@@ -317,6 +318,20 @@ def _is_copy_fusion(op: Op, comps: Dict[str, "Computation"]) -> bool:
     return all(o.kind in _PURE_MOVEMENT for o in called.ops)
 
 
+def _while_loop(op: Op, comps: Dict[str, Computation]
+                ) -> Optional[Tuple[str, int]]:
+    """(body computation, trip count) of a while op: the trip count from
+    ``known_trip_count`` when present, else the loop-condition constant."""
+    wm = _WHILE_RE.search(op.line)
+    if not wm:
+        return None
+    cond = wm.group(1) or wm.group(4)
+    tm = _TRIP_RE.search(op.line)
+    trips = (int(tm.group(1)) if tm else
+             max(comps.get(cond, Computation("")).max_const, 1))
+    return wm.group(2) or wm.group(3), trips
+
+
 @dataclass
 class Totals:
     flops: float = 0.0
@@ -369,13 +384,9 @@ def analyze_text(text: str, n_devices: int) -> Dict[str, float]:
                     t.traffic += b
             # descend
             if op.kind == "while":
-                wm = _WHILE_RE.search(op.line)
-                if wm:
-                    cond = wm.group(1) or wm.group(4)
-                    body = wm.group(2) or wm.group(3)
-                    tm = _TRIP_RE.search(op.line)
-                    trips = (int(tm.group(1)) if tm else
-                             max(comps.get(cond, Computation("")).max_const, 1))
+                loop = _while_loop(op, comps)
+                if loop:
+                    body, trips = loop
                     t.add(walk(body, inside_fusion, depth + 1), trips)
             elif op.kind == "fusion":
                 cm = _CALLS_RE.search(op.line)
@@ -400,3 +411,26 @@ def collective_bytes(text: str, n_devices: int) -> Dict[str, float]:
     res = analyze_text(text, n_devices)
     return {k: v for k, v in res.items()
             if k not in ("flops", "traffic_bytes")}
+
+
+def loop_all_reduces(text: str, n_devices: int) -> List[Dict]:
+    """Every while loop of a compiled program with the all-reduces its
+    body issues per iteration: ``trips`` (the trip count),
+    ``all_reduces`` (the count in the body) and ``group_sizes`` (the
+    replica-group size of each).  A PT step that fuses its tracks once
+    per block shows one loop with ``all_reduces == 1`` grouped over the
+    track axis, and ``trips`` track blocks."""
+    comps, _ = parse_computations(text)
+    loops: Dict[str, Dict] = {}
+    for comp in comps.values():
+        for op in comp.ops:
+            loop = _while_loop(op, comps) if op.kind == "while" else None
+            if not loop:
+                continue
+            body, trips = loop
+            ars = [o for o in comps.get(body, Computation("")).ops
+                   if o.kind in ("all-reduce", "all-reduce-start")]
+            loops[body] = {"trips": trips, "all_reduces": len(ars),
+                           "group_sizes": [_group_size(o.line, n_devices)
+                                           for o in ars]}
+    return list(loops.values())

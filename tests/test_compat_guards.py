@@ -1,13 +1,13 @@
-"""Guards for the pinned jax (0.4.37): newer-jax APIs must only be
-touched through ``repro.common.compat`` so test collection (and every
-import) keeps working on the pin.
+"""Guards for the installed jax.
 
-Two layers of defense:
-  * a source scan: raw uses of the known-absent APIs anywhere outside
-    the compat shim fail fast with the offending file/line;
+  * a source scan: ``jax.make_mesh`` defaults to ``Explicit`` axis
+    types, under which ``with_sharding_constraint`` refuses the specs the
+    sharding rules emit ("can only refer to Auto axes of the mesh").  A
+    raw ``jax.make_mesh(`` or ``Mesh(`` anywhere outside
+    ``repro.common.compat`` fails with the offending file/line;
   * an import sweep: every repro module must import cleanly (an
-    import-time use of a missing API breaks pytest collection — this
-    pins it to a named test instead).
+    import-time failure breaks pytest collection — this pins it to a
+    named test instead).
 """
 import importlib
 import pkgutil
@@ -16,43 +16,28 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
 
-# APIs absent from jax 0.4.37 (see repro/common/compat.py); each pattern
-# names its sanctioned replacement in the failure message.
-PINNED_APIS = [
-    (re.compile(r"from\s+jax\.sharding\s+import\s+[^\n]*\bAxisType\b"),
-     "import AxisType via repro.common.compat (guarded try/except)"),
-    (re.compile(r"jax\.sharding\.AxisType"),
-     "use repro.common.compat.AxisType"),
-    (re.compile(r"axis_types\s*="),
-     "build meshes via repro.common.compat.make_mesh/mesh_from_devices"),
-    (re.compile(r"jax\.lax\.axis_size"),
-     "use repro.common.compat.axis_size (psum(1, axis) on 0.4.x)"),
-    (re.compile(r"jax\.shard_map"),
-     "use repro.common.compat.shard_map"),
-    (re.compile(r"check_vma\s*="),
-     "use repro.common.compat.shard_map (0.4.x wants check_rep=)"),
-]
+RAW_MESH = re.compile(r"jax\.make_mesh\(|\bMesh\(")
 
-SCAN_DIRS = ("src", "tests", "benchmarks", "examples", "tools")
+SCAN = [ROOT / "chip_smoke.py"] + [
+    path for d in ("src", "tests", "benchmarks", "examples", "tools")
+    for path in sorted((ROOT / d).rglob("*.py"))]
 EXEMPT = {Path("src/repro/common/compat.py"),
           Path("tests/test_compat_guards.py")}
 
 
 def test_no_raw_pinned_apis_outside_compat():
     offenders = []
-    for d in SCAN_DIRS:
-        for path in sorted((ROOT / d).rglob("*.py")):
-            rel = path.relative_to(ROOT)
-            if rel in EXEMPT:
-                continue
-            for lineno, line in enumerate(
-                    path.read_text().splitlines(), start=1):
-                for pat, fix in PINNED_APIS:
-                    if pat.search(line):
-                        offenders.append(f"{rel}:{lineno}: {line.strip()}"
-                                         f"  ->  {fix}")
+    for path in SCAN:
+        rel = path.relative_to(ROOT)
+        if rel in EXEMPT:
+            continue
+        for lineno, line in enumerate(path.read_text().splitlines(),
+                                      start=1):
+            if RAW_MESH.search(line):
+                offenders.append(f"{rel}:{lineno}: {line.strip()}")
     assert not offenders, (
-        "raw jax>=0.5 API use (breaks the jax 0.4.37 pin):\n"
+        "raw mesh construction (Explicit axis types); build meshes via "
+        "repro.common.compat.make_mesh/mesh_from_devices:\n"
         + "\n".join(offenders))
 
 

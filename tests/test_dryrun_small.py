@@ -17,6 +17,7 @@ ROOT = Path(__file__).resolve().parent.parent
 
 def _run(code: str) -> dict:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # virtual CPU devices; never a chip
     env["PYTHONPATH"] = str(ROOT / "src")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", code], env=env,
@@ -32,12 +33,13 @@ def test_dryrun_reduced_cells_on_virtual_mesh():
         os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
         import json
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.common.types import ShapeSpec
         from repro.configs import reduced_config
         from repro.launch import steps as S
         from repro.runtime import sharding as sh
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         out = {}
         for arch, kind in (('gemma3-4b', 'train'),
                            ('falcon-mamba-7b', 'decode'),

@@ -28,6 +28,7 @@ slow = pytest.mark.slow                # subprocess compiles take minutes
 
 def _run(code: str) -> dict:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # virtual CPU devices; never a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(ROOT / "src")
     out = subprocess.run([sys.executable, "-c", code], env=env,
@@ -54,6 +55,7 @@ def test_moe_sharded_equals_single():
     res = _run(textwrap.dedent("""
         import json
         import jax, jax.numpy as jnp, numpy as np
+        from repro.common.compat import make_mesh
         from repro.configs import reduced_config
         from repro.models import moe as moe_lib
         from repro.runtime.parallel import NO_PARALLEL, Parallelism, TRAIN_RULES
@@ -68,7 +70,7 @@ def test_moe_sharded_equals_single():
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model))
         y0, aux0 = moe_lib.moe_apply(params, x, cfg=cfg, par=NO_PARALLEL)
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         par = Parallelism(mesh=mesh, rules=dict(TRAIN_RULES))
         y1, aux1 = jax.jit(lambda p, x: moe_lib.moe_apply(
             p, x, cfg=cfg, par=par))(params, x)
@@ -84,6 +86,7 @@ def test_train_step_sharded_equals_single():
     res = _run(textwrap.dedent("""
         import json
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.configs import reduced_config
         from repro.launch import steps as S
         from repro.runtime import sharding as sh
@@ -102,7 +105,7 @@ def test_train_step_sharded_equals_single():
         p0, o0, m0 = jax.jit(step0)(params, init0(params), batch)
 
         # 2x4 mesh
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         par1 = S.build_parallelism(cfg, 'train', mesh)
         step1, init1, _ = S.make_train_step(cfg, par1, microbatches=2)
         psh = sh.param_shardings(params, cfg, par1)
@@ -128,11 +131,12 @@ def test_pt_sync_points_in_compiled_hlo():
     res = _run(textwrap.dedent("""
         import json
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.configs import pt_paper
         from repro.core.track import pt_ify, pt_sync_points
         from repro.launch import steps as S
-        from repro.runtime import sharding as sh
         from repro.roofline import hlo as H
+        from repro.runtime import sharding as sh
 
         def collectives(cfg, mesh, par):
             fns = S.model_fns(cfg)
@@ -150,12 +154,12 @@ def test_pt_sync_points_in_compiled_hlo():
 
         L, D = 8, 4
         dense = pt_paper.reduced_dense().replace(n_layers=L, remat=False)
-        mesh_d = jax.make_mesh((1, 8), ('data', 'model'))
+        mesh_d = make_mesh((1, 8), ('data', 'model'))
         par_d = S.build_parallelism(dense, 'train', mesh_d)
         ar_dense = collectives(dense, mesh_d, par_d)
 
         pt = pt_ify(dense, 4, D, width_mult=16).replace(remat=False)
-        mesh_t = jax.make_mesh((2, 4), ('data', 'track'))
+        mesh_t = make_mesh((2, 4), ('data', 'track'))
         par_t = S.build_parallelism(pt, 'train', mesh_t)
         ar_pt = collectives(pt, mesh_t, par_t)
         print(json.dumps({'dense': int(ar_dense), 'pt': int(ar_pt),
@@ -177,17 +181,19 @@ def test_pt_paged_decode_one_allreduce_per_track_block():
     scatter/gather stays track-local (the pool's track dim shards with
     the params) and adds no collectives."""
     res = _run(textwrap.dedent("""
-        import json, re
+        import json
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.common.paged import wrap_paged
         from repro.configs import pt_paper
         from repro.launch import steps as S
+        from repro.roofline import hlo as H
         from repro.runtime import sharding as sh
         from repro.serving.cache import PagedKVCache
 
         cfg = pt_paper.reduced_pt(2).replace(remat=False)  # 8 layers, D=2
         n_tracks = cfg.pt.n_tracks
-        mesh = jax.make_mesh((2, n_tracks), ('data', 'track'))
+        mesh = make_mesh((2, n_tracks), ('data', 'track'))
         par = S.build_parallelism(cfg, 'decode', mesh)
         fns = S.model_fns(cfg)
         ps = jax.eval_shape(lambda: fns['init'](jax.random.PRNGKey(0), cfg))
@@ -208,30 +214,10 @@ def test_pt_paged_decode_one_allreduce_per_track_block():
         txt = jax.jit(step, in_shardings=(psh, None, None, None, None)) \\
             .lower(ps, cache, tok, pos, tbl).compile().as_text()
 
-        comps, cur = {}, None
-        for line in txt.splitlines():
-            if line and not line[0].isspace() and '{' in line:
-                m = re.match(r'(?:ENTRY\\s+)?%?([\\w\\.\\-]+)', line.strip())
-                cur = m.group(1) if m else None
-                comps[cur] = []
-            elif cur is not None:
-                comps[cur].append(line)
-        bodies = set(re.findall(r'body=%?([\\w\\.\\-]+)', txt))
-        ar = re.compile(r'=\\s*\\S+\\s+all-reduce(?:-start)?\\(')
-        per_body = {b: sum(1 for l in comps.get(b, ()) if ar.search(l))
-                    for b in bodies}
-        sizes = []
-        for b in bodies:
-            for l in comps.get(b, ()):
-                if ar.search(l):
-                    g = re.search(r'replica_groups=\\{\\{([\\d,]+)\\}', l)
-                    if g:
-                        sizes.append(len(g.group(1).split(',')))
-                    g = re.search(r'replica_groups=\\[\\d+,(\\d+)\\]<=', l)
-                    if g:
-                        sizes.append(int(g.group(1)))
-        print(json.dumps({'per_body': sorted(per_body.values()),
-                          'group_sizes': sizes,
+        loops = H.loop_all_reduces(txt, 8)
+        print(json.dumps({'per_body': sorted(l['all_reduces'] for l in loops),
+                          'group_sizes': [g for l in loops
+                                          for g in l['group_sizes']],
                           'n_tracks': n_tracks}))
     """))
     assert res["per_body"].count(1) == 1 and max(res["per_body"]) == 1, res
@@ -245,15 +231,17 @@ def test_pt_decode_one_allreduce_per_track_block():
     while body contains EXACTLY ONE cross-track all-reduce (the fusion
     mean) — grouped over the n_tracks mesh axis."""
     res = _run(textwrap.dedent("""
-        import json, re
+        import json
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.configs import pt_paper
         from repro.launch import steps as S
+        from repro.roofline import hlo as H
         from repro.runtime import sharding as sh
 
         cfg = pt_paper.reduced_pt(2).replace(remat=False)  # 8 layers, D=2
         n_tracks = cfg.pt.n_tracks
-        mesh = jax.make_mesh((2, n_tracks), ('data', 'track'))
+        mesh = make_mesh((2, n_tracks), ('data', 'track'))
         par = S.build_parallelism(cfg, 'decode', mesh)
         fns = S.model_fns(cfg)
         ps = jax.eval_shape(lambda: fns['init'](jax.random.PRNGKey(0), cfg))
@@ -269,32 +257,10 @@ def test_pt_decode_one_allreduce_per_track_block():
         txt = jax.jit(step, in_shardings=(psh, None, None, None)) \\
             .lower(ps, cache, tok, pos).compile().as_text()
 
-        # split the HLO into named computations
-        comps, cur = {}, None
-        for line in txt.splitlines():
-            if line and not line[0].isspace() and '{' in line:
-                m = re.match(r'(?:ENTRY\\s+)?%?([\\w\\.\\-]+)', line.strip())
-                cur = m.group(1) if m else None
-                comps[cur] = []
-            elif cur is not None:
-                comps[cur].append(line)
-        bodies = set(re.findall(r'body=%?([\\w\\.\\-]+)', txt))
-        ar = re.compile(r'=\\s*\\S+\\s+all-reduce(?:-start)?\\(')
-        per_body = {b: sum(1 for l in comps.get(b, ()) if ar.search(l))
-                    for b in bodies}
-        # group sizes of the all-reduces inside while bodies
-        sizes = []
-        for b in bodies:
-            for l in comps.get(b, ()):
-                if ar.search(l):
-                    g = re.search(r'replica_groups=\\{\\{([\\d,]+)\\}', l)
-                    if g:                         # explicit-list format
-                        sizes.append(len(g.group(1).split(',')))
-                    g = re.search(r'replica_groups=\\[\\d+,(\\d+)\\]<=', l)
-                    if g:                         # iota format [n,size]<=[N]
-                        sizes.append(int(g.group(1)))
-        print(json.dumps({'per_body': sorted(per_body.values()),
-                          'group_sizes': sizes,
+        loops = H.loop_all_reduces(txt, 8)
+        print(json.dumps({'per_body': sorted(l['all_reduces'] for l in loops),
+                          'group_sizes': [g for l in loops
+                                          for g in l['group_sizes']],
                           'n_tracks': n_tracks}))
     """))
     # exactly one loop body carries a collective — the track-block scan —
@@ -315,13 +281,14 @@ def test_pt_draft_step_zero_cross_track_allreduces():
     res = _run(textwrap.dedent("""
         import json, re
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.configs import pt_paper
         from repro.core import track as pt_lib
         from repro.launch import steps as S
 
         cfg = pt_paper.reduced_pt(2).replace(remat=False)  # 8 layers, D=2
         n_tracks = cfg.pt.n_tracks
-        mesh = jax.make_mesh((2, n_tracks), ('data', 'track'))
+        mesh = make_mesh((2, n_tracks), ('data', 'track'))
         par = S.build_parallelism(cfg, 'decode', mesh)
         draft, draft_cfg = S.make_draft_step(cfg, par, draft_tracks=2)
 
@@ -348,17 +315,19 @@ def test_pt_verify_step_one_allreduce_per_track_block():
     cross-track all-reduce per track-block scan iteration — scoring a
     whole draft costs the same L/D sync points as emitting one token."""
     res = _run(textwrap.dedent("""
-        import json, re
+        import json
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.common.paged import wrap_paged
         from repro.configs import pt_paper
         from repro.launch import steps as S
+        from repro.roofline import hlo as H
         from repro.runtime import sharding as sh
         from repro.serving.cache import PagedKVCache
 
         cfg = pt_paper.reduced_pt(2).replace(remat=False)  # 8 layers, D=2
         n_tracks = cfg.pt.n_tracks
-        mesh = jax.make_mesh((2, n_tracks), ('data', 'track'))
+        mesh = make_mesh((2, n_tracks), ('data', 'track'))
         par = S.build_parallelism(cfg, 'decode', mesh)
         fns = S.model_fns(cfg)
         ps = jax.eval_shape(lambda: fns['init'](jax.random.PRNGKey(0), cfg))
@@ -378,30 +347,10 @@ def test_pt_verify_step_one_allreduce_per_track_block():
         txt = jax.jit(verify, in_shardings=(psh, None, None, None, None)) \\
             .lower(ps, cache, tok, pos, tbl).compile().as_text()
 
-        comps, cur = {}, None
-        for line in txt.splitlines():
-            if line and not line[0].isspace() and '{' in line:
-                m = re.match(r'(?:ENTRY\\s+)?%?([\\w\\.\\-]+)', line.strip())
-                cur = m.group(1) if m else None
-                comps[cur] = []
-            elif cur is not None:
-                comps[cur].append(line)
-        bodies = set(re.findall(r'body=%?([\\w\\.\\-]+)', txt))
-        ar = re.compile(r'=\\s*\\S+\\s+all-reduce(?:-start)?\\(')
-        per_body = {b: sum(1 for l in comps.get(b, ()) if ar.search(l))
-                    for b in bodies}
-        sizes = []
-        for b in bodies:
-            for l in comps.get(b, ()):
-                if ar.search(l):
-                    g = re.search(r'replica_groups=\\{\\{([\\d,]+)\\}', l)
-                    if g:
-                        sizes.append(len(g.group(1).split(',')))
-                    g = re.search(r'replica_groups=\\[\\d+,(\\d+)\\]<=', l)
-                    if g:
-                        sizes.append(int(g.group(1)))
-        print(json.dumps({'per_body': sorted(per_body.values()),
-                          'group_sizes': sizes,
+        loops = H.loop_all_reduces(txt, 8)
+        print(json.dumps({'per_body': sorted(l['all_reduces'] for l in loops),
+                          'group_sizes': [g for l in loops
+                                          for g in l['group_sizes']],
                           'n_tracks': n_tracks}))
     """))
     assert res["per_body"].count(1) == 1 and max(res["per_body"]) == 1, res
@@ -416,18 +365,20 @@ def test_pt_quantized_paged_decode_one_allreduce_per_track_block():
     pool, local to every track) still compile to exactly ONE cross-track
     all-reduce per track-block scan iteration."""
     res = _run(textwrap.dedent("""
-        import json, re
+        import json
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.common.paged import wrap_paged
         from repro.common.quant import quantize_params
         from repro.configs import pt_paper
         from repro.launch import steps as S
+        from repro.roofline import hlo as H
         from repro.runtime import sharding as sh
         from repro.serving.cache import PagedKVCache
 
         cfg = pt_paper.reduced_pt(2).replace(remat=False)  # 8 layers, D=2
         n_tracks = cfg.pt.n_tracks
-        mesh = jax.make_mesh((2, n_tracks), ('data', 'track'))
+        mesh = make_mesh((2, n_tracks), ('data', 'track'))
         par = S.build_parallelism(cfg, 'decode', mesh)
         fns = S.model_fns(cfg)
         ps = jax.eval_shape(lambda: quantize_params(
@@ -450,30 +401,10 @@ def test_pt_quantized_paged_decode_one_allreduce_per_track_block():
         txt = jax.jit(step, in_shardings=(psh, None, None, None, None)) \\
             .lower(ps, cache, tok, pos, tbl).compile().as_text()
 
-        comps, cur = {}, None
-        for line in txt.splitlines():
-            if line and not line[0].isspace() and '{' in line:
-                m = re.match(r'(?:ENTRY\\s+)?%?([\\w\\.\\-]+)', line.strip())
-                cur = m.group(1) if m else None
-                comps[cur] = []
-            elif cur is not None:
-                comps[cur].append(line)
-        bodies = set(re.findall(r'body=%?([\\w\\.\\-]+)', txt))
-        ar = re.compile(r'=\\s*\\S+\\s+all-reduce(?:-start)?\\(')
-        per_body = {b: sum(1 for l in comps.get(b, ()) if ar.search(l))
-                    for b in bodies}
-        sizes = []
-        for b in bodies:
-            for l in comps.get(b, ()):
-                if ar.search(l):
-                    g = re.search(r'replica_groups=\\{\\{([\\d,]+)\\}', l)
-                    if g:
-                        sizes.append(len(g.group(1).split(',')))
-                    g = re.search(r'replica_groups=\\[\\d+,(\\d+)\\]<=', l)
-                    if g:
-                        sizes.append(int(g.group(1)))
-        print(json.dumps({'per_body': sorted(per_body.values()),
-                          'group_sizes': sizes,
+        loops = H.loop_all_reduces(txt, 8)
+        print(json.dumps({'per_body': sorted(l['all_reduces'] for l in loops),
+                          'group_sizes': [g for l in loops
+                                          for g in l['group_sizes']],
                           'n_tracks': n_tracks}))
     """))
     assert res["per_body"].count(1) == 1 and max(res["per_body"]) == 1, res
@@ -489,6 +420,7 @@ def test_pt_quantized_draft_step_zero_cross_track_allreduces():
     res = _run(textwrap.dedent("""
         import json, re
         import jax, jax.numpy as jnp
+        from repro.common.compat import make_mesh
         from repro.common.quant import quantize_params
         from repro.configs import pt_paper
         from repro.core import track as pt_lib
@@ -496,7 +428,7 @@ def test_pt_quantized_draft_step_zero_cross_track_allreduces():
 
         cfg = pt_paper.reduced_pt(2).replace(remat=False)  # 8 layers, D=2
         n_tracks = cfg.pt.n_tracks
-        mesh = jax.make_mesh((2, n_tracks), ('data', 'track'))
+        mesh = make_mesh((2, n_tracks), ('data', 'track'))
         par = S.build_parallelism(cfg, 'decode', mesh)
         draft, draft_cfg = S.make_draft_step(cfg, par, draft_tracks=2)
 

@@ -65,6 +65,21 @@ def test_parser_known_trip_count_overrides():
     np.testing.assert_allclose(res["flops"], 2 * 16 * 64 * 64 * 3)
 
 
+def test_loop_all_reduces_reads_tpu_layouts():
+    """One entry per while loop: trips x all-reduces per iteration.  TPU
+    HLO writes tile layouts with parens inside tuple types; the loop must
+    still be found."""
+    want = [{"trips": 5, "all_reduces": 1, "group_sizes": [8]}]
+    assert hlo.loop_all_reduces(_HLO, 8) == want
+    tpu = _HLO.replace(
+        "%w2 = (s32[], f32[16,64]) while(",
+        "%w2 = (s32[]{:T(128)}, f32[16,64]{1,0:T(8,128)S(1)}) while(")
+    assert "T(8,128)" in tpu
+    assert hlo.loop_all_reduces(tpu, 8) == want
+    np.testing.assert_allclose(hlo.analyze_text(tpu, 8)["flops"],
+                               2 * 16 * 64 * 64 * 5)
+
+
 def test_wire_bytes_formulas():
     assert hlo._wire_bytes("all-reduce", 100, 4) == 2 * 0.75 * 100
     assert hlo._wire_bytes("all-gather", 100, 4) == 0.75 * 100
