@@ -592,13 +592,11 @@ def bench_pipelined(json_path: str | None = None) -> dict:
         "throughput_tok_s": piped_m["throughput_tok_s"],
         "sync_throughput_tok_s": sync_m["throughput_tok_s"],
         "steps_in_flight": piped_m["steps_in_flight"],
-        "dispatch_gap_ms": piped_m["dispatch_gap_ms"],
     }
     print(f"pipelined,bitwise_equal {out['bitwise_equal_sync']},"
           f"tpot {out['sync_tpot_mean_ms']:.2f} -> "
           f"{out['tpot_ms']['mean']:.2f} ms,"
-          f"inflight_peak {out['steps_in_flight']},"
-          f"dispatch_gap_p50 {out['dispatch_gap_ms']['p50']:.2f} ms")
+          f"inflight_peak {out['steps_in_flight']}")
     if json_path:
         _merge_json(json_path, "pipelined", out)
     return out

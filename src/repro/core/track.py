@@ -168,6 +168,7 @@ def init_pt(key, cfg: ModelConfig):
 # fusion + track-vmapped layer
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("track_fuse")
 def _fuse(h: jax.Array, cfg: ModelConfig, par: Parallelism) -> jax.Array:
     """All-reduce across tracks: h [n, B, S, d] -> fused [B, S, d].
 

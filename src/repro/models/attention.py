@@ -351,6 +351,7 @@ def attention_decode(params, x: jax.Array, cache: Tuple[jax.Array, jax.Array],
     return out, (k_cache, v_cache)
 
 
+@jax.named_scope("kv_write")
 def _scatter_cache(cache: jax.Array, new: jax.Array, slot: jax.Array,
                    par: Parallelism,
                    active: Optional[jax.Array] = None) -> jax.Array:
@@ -368,6 +369,7 @@ def _scatter_cache(cache: jax.Array, new: jax.Array, slot: jax.Array,
 # paged decode / chunked prefill (block-pool caches)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kv_write")
 def pool_write(leaf: PagedLeaf, rows: jax.Array,
                w_idx: jax.Array) -> PagedLeaf:
     """Scatter new rows into one pool leaf at flat pool rows ``w_idx``.
@@ -398,6 +400,7 @@ def _paged_write(k_leaf: PagedLeaf, v_leaf: PagedLeaf, k_new: jax.Array,
     return pool_write(k_leaf, k_new, w_idx), pool_write(v_leaf, v_new, w_idx)
 
 
+@jax.named_scope("kv_gather")
 def _paged_gather(pool: jax.Array, block_table: jax.Array, bs: int,
                   par: Parallelism) -> jax.Array:
     """Assemble the contiguous per-slot view [B, S_cap, KH, hd] from a
@@ -411,6 +414,7 @@ def _paged_gather(pool: jax.Array, block_table: jax.Array, bs: int,
     return par.cs(flat[idx], "batch", "kv_seq", "kv_heads", None)
 
 
+@jax.named_scope("kv_gather")
 def pool_read(leaf: PagedLeaf, block_table: jax.Array, bs: int) -> jax.Array:
     """Gather the contiguous per-slot view [B, S_cap, ...] of one pool
     leaf through the block table, dequantizing int8 leaves.  Trailing

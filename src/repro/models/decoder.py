@@ -102,6 +102,7 @@ def _sinusoid(positions: jax.Array, d: int) -> jax.Array:
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
+@jax.named_scope("embed")
 def _embed(params, inputs: jax.Array, cfg: ModelConfig,
            positions: jax.Array, par: Parallelism) -> jax.Array:
     dtype = model_dtype(cfg)
@@ -117,6 +118,7 @@ def _embed(params, inputs: jax.Array, cfg: ModelConfig,
     return par.cs(h, "batch", "seq", "d_model")
 
 
+@jax.named_scope("lm_head")
 def _head(params, h: jax.Array, cfg: ModelConfig, par: Parallelism):
     h = apply_norm(cfg.norm, params["final_norm"], h, eps=cfg.norm_eps)
     if cfg.logits_fp32:

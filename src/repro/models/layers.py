@@ -79,6 +79,9 @@ def _scattered_rows(cache, new_rows, slots):
         lambda l, r: l.at[slots].set(r.astype(l.dtype)), cache, new_rows)
 
 
+# "attn" names the token mixer of every layer kind (attention, or an
+# SSM / RG-LRU recurrence) in the step programs' op metadata
+@jax.named_scope("attn")
 def _mixer(params, h, *, cfg, spec, mode, positions, pos, cache, par,
            lengths=None, block_table=None, kv_max_len=None,
            slots=None, chunk_lens=None, active=None):

@@ -43,6 +43,7 @@ def _act(kind: str, g: jax.Array) -> jax.Array:
     raise ValueError(f"unknown activation {kind!r}")
 
 
+@jax.named_scope("mlp")
 def mlp_apply(params, x: jax.Array, kind: str,
               par: Parallelism = NO_PARALLEL,
               use_pallas: bool = False) -> jax.Array:

@@ -225,6 +225,7 @@ def capacity(n_tokens_sub: int, cfg: ModelConfig) -> int:
     return max(4, -(-c // 4) * 4)
 
 
+@jax.named_scope("mlp")
 def moe_apply(params, x: jax.Array, *, cfg: ModelConfig,
               par: Parallelism = NO_PARALLEL):
     """x: [B, S, d] -> (y [B, S, d], aux loss scalar)."""

@@ -116,6 +116,32 @@ from repro.serving.sampler import (SALT_DRAFT, SALT_SAMPLE, SampleParams,
 
 RECURRENT_MIXERS = ("mamba", "rglru")
 
+# Step-phase spans on the profiler's host plane.  A span costs about a
+# microsecond when no profile is being taken.  Capture a profile around
+# the ``Engine.step()`` loop (``jax.profiler.trace``) to see them:
+#   serve.step    one Engine.step(); args: step index, rows queued,
+#                 prefilling and decoding, and ``t`` (perf_counter_ns at
+#                 entry, which puts every Request mark on the trace clock)
+#   serve.admit   admission (and bucketed prefill); args: admitted,
+#                 prefix-hit tokens, rids
+#   serve.prefill one chunk of every prefilling row; args: rows, real and
+#                 padded tokens, rids
+#   serve.cow     copy-on-write of shared blocks before decode
+#   serve.decode  the decode/verify call (pipelined: its dispatch); rids
+#   serve.emit    applying sampled tokens: on_token callbacks, finishing
+#   serve.inputs  a runner call's argument transfers and block table
+#   serve.launch  the jitted program's call
+#   serve.fetch   the blocking device-to-host read of its result
+SPANS = ("serve.step", "serve.admit", "serve.prefill", "serve.cow",
+         "serve.decode", "serve.emit", "serve.inputs", "serve.launch",
+         "serve.fetch")
+_span = jax.profiler.TraceAnnotation
+
+
+def _rids(rows: Sequence[Tuple[int, "Request"]]) -> str:
+    """Request ids of a phase's rows, as one span argument."""
+    return " ".join(str(r.rid) for _, r in rows)
+
 
 class RequestState(enum.Enum):
     QUEUED = "queued"
@@ -156,8 +182,15 @@ class Request:
     preemptions: int = 0               # times evicted + requeued
     # monotonic (perf_counter) latency marks — immune to clock steps
     t_submit: float = 0.0
+    t_admitted: float = 0.0            # first slot (a preemption-resume
+                                       # keeps it; a rolled-back first
+                                       # admission clears it)
     t_first: float = 0.0
     t_done: float = 0.0
+    chunk_step_tokens: int = 0         # output tokens after the first
+                                       # that waited behind a chunk or
+                                       # prefill program (one ran since
+                                       # the request's previous token)
     t_submit_wall: float = 0.0         # wall-clock, for log timestamps only
 
     @property
@@ -212,7 +245,6 @@ class EngineMetrics:
         self.watchdog_fires = 0
         self.transfer_faults = 0       # TransferFault steps retried
         # pipelined stepping
-        self.dispatch_gaps: List[float] = []   # s between step dispatches
         self.steps_in_flight = 0       # peak dispatched-but-unfetched steps
 
     def start(self) -> None:
@@ -277,7 +309,6 @@ class EngineMetrics:
             "timed_out": self.timed_out,
             "watchdog_fires": self.watchdog_fires,
             "transfer_faults": self.transfer_faults,
-            "dispatch_gap_ms": pct(self.dispatch_gaps),
             "steps_in_flight": self.steps_in_flight,
         }
 
@@ -897,39 +928,51 @@ class ModelRunner:
         for i, p in enumerate(prompts):
             tokens[i, :len(p)] = p
             lengths[i] = len(p)
-        temps, tks, tps = stack_params(params_list)
-        toks, cache = self._prefill(self.params, jnp.asarray(tokens),
-                                    jnp.asarray(lengths),
-                                    jnp.asarray(seeds, jnp.uint32),
-                                    jnp.asarray(counters, jnp.int32),
-                                    jnp.asarray(temps), jnp.asarray(tks),
-                                    jnp.asarray(tps))
-        table_rows = (self.kv.table_rows(slots) if self.paged
-                      else jnp.zeros((n, 1), jnp.int32))
-        self.cache = self._insert(self.cache, cache,
-                                  jnp.asarray(slots, jnp.int32), table_rows)
+        with _span("serve.inputs"):
+            temps, tks, tps = stack_params(params_list)
+            args = (self.params, jnp.asarray(tokens), jnp.asarray(lengths),
+                    jnp.asarray(seeds, jnp.uint32),
+                    jnp.asarray(counters, jnp.int32), jnp.asarray(temps),
+                    jnp.asarray(tks), jnp.asarray(tps))
+            table_rows = (self.kv.table_rows(slots) if self.paged
+                          else jnp.zeros((n, 1), jnp.int32))
+            slots_d = jnp.asarray(slots, jnp.int32)
+        with _span("serve.launch"):
+            toks, cache = self._prefill(*args)
+            self.cache = self._insert(self.cache, cache, slots_d, table_rows)
         self.prefill_shapes.add((n, bucket))
         self.prefill_calls += 1
         self._maybe_inject_transfer("prefill")
-        return np.asarray(toks)
+        with _span("serve.fetch"):
+            return np.asarray(toks)
 
     def chunk(self, toks: np.ndarray, pos: np.ndarray, slots: Sequence[int],
               last_idx: np.ndarray, seeds: Sequence[int],
               counters: Sequence[int],
               params_list: Sequence[SampleParams]) -> np.ndarray:
         """One chunk step for the currently-prefilling requests."""
-        temps, tks, tps = stack_params(params_list)
-        self.cache, cand = self._chunk(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
-            self.kv.table_rows(slots), jnp.asarray(slots, jnp.int32),
-            jnp.asarray(last_idx),
-            jnp.asarray(seeds, jnp.uint32),
-            jnp.asarray(counters, jnp.int32),
-            jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps))
+        return self._run_chunk(toks, pos, slots, last_idx, seeds, counters,
+                               params_list, "chunk")
+
+    def _run_chunk(self, toks, pos, slots, last_idx, seeds, counters,
+                   params_list, site: str) -> np.ndarray:
+        """The chunk program on ``toks`` [n, C] at positions ``pos``;
+        returns each row's candidate token (fault site ``site``)."""
+        with _span("serve.inputs"):
+            temps, tks, tps = stack_params(params_list)
+            args = (self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(pos), self.kv.table_rows(slots),
+                    jnp.asarray(slots, jnp.int32), jnp.asarray(last_idx),
+                    jnp.asarray(seeds, jnp.uint32),
+                    jnp.asarray(counters, jnp.int32), jnp.asarray(temps),
+                    jnp.asarray(tks), jnp.asarray(tps))
+        with _span("serve.launch"):
+            self.cache, cand = self._chunk(*args)
         self.chunk_shapes.add(tuple(toks.shape))
         self.chunk_calls += 1
-        self._maybe_inject_transfer("chunk")
-        return np.asarray(cand)
+        self._maybe_inject_transfer(site)
+        with _span("serve.fetch"):
+            return np.asarray(cand)
 
     def warm_prefill(self, prompts: Sequence[Sequence[int]],
                      matched: Sequence[int], slots: Sequence[int],
@@ -951,18 +994,8 @@ class ModelRunner:
             toks[i, :len(p) - m] = p[m:]
             pos[i] = m
             last_idx[i] = len(p) - m - 1
-        temps, tks, tps = stack_params(params_list)
-        self.cache, cand = self._chunk(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
-            self.kv.table_rows(slots), jnp.asarray(slots, jnp.int32),
-            jnp.asarray(last_idx),
-            jnp.asarray(seeds, jnp.uint32),
-            jnp.asarray(counters, jnp.int32),
-            jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps))
-        self.chunk_shapes.add((n, bucket))
-        self.chunk_calls += 1
-        self._maybe_inject_transfer("warm_prefill")
-        return np.asarray(cand)
+        return self._run_chunk(toks, pos, slots, last_idx, seeds, counters,
+                               params_list, "warm_prefill")
 
     def copy_blocks(self, pairs: Sequence[Tuple[int, int]]) -> None:
         """Apply copy-on-write pairs from ``kv.ensure_writable`` to the
@@ -1090,39 +1123,44 @@ class ModelRunner:
                         override=None, extra_len: int = 0
                         ) -> Dict[str, Any]:
         """Dispatch one decode step; no host transfer happens here."""
-        max_len = None
-        if self.paged:
-            # lanes not actively decoding (idle, or mid-chunked-prefill)
-            # get zeroed table rows: their stale-position writes land in
-            # the trash block, never in blocks owned by live requests.
-            table = self._masked_table(active)
-            # the paged kernel sweeps only the live blocks.  Only the
-            # Pallas path consumes the bound — the jnp reference path
-            # stays a single compile (and bit-identical to the dense
-            # cache).  ``extra_len`` widens the bound by the tokens
-            # in-flight steps may have advanced past the host mirror.
-            if self.cfg.use_pallas:
-                max_len = self._live_max_len(pos, active, extra=extra_len)
-        else:
-            table = jnp.zeros((len(toks), 1), jnp.int32)
-        tok_d, pos_d = jnp.asarray(toks), jnp.asarray(pos)
-        counts_d = jnp.asarray(counts, jnp.int32)
-        rem_d = jnp.asarray(remaining)
-        if carry is not None:
-            tok_d, pos_d, counts_d, rem_d = self._advance_decode(
-                carry["packed"], carry["tok"], carry["pos"],
-                carry["counts"], carry["remaining"],
-                jnp.asarray(override), tok_d, pos_d, counts_d, rem_d)
-        args = (self.params, self.cache, tok_d, pos_d,
-                jnp.asarray(active), table, jnp.asarray(seeds, jnp.uint32),
-                counts_d, jnp.asarray(temps), jnp.asarray(tks),
-                jnp.asarray(tps), jnp.asarray(eos), rem_d)
-        planned = self._planned.get(("decode", max_len))
-        if planned is not None:
-            self.cache, packed = planned(*args)
-            self.planned_hits += 1
-        else:
-            self.cache, packed = self._decode(*args, max_len=max_len)
+        with _span("serve.inputs"):
+            max_len = None
+            if self.paged:
+                # lanes not actively decoding (idle, or mid-chunked-
+                # prefill) get zeroed table rows: their stale-position
+                # writes land in the trash block, never in blocks owned
+                # by live requests.
+                table = self._masked_table(active)
+                # the paged kernel sweeps only the live blocks.  Only the
+                # Pallas path consumes the bound — the jnp reference path
+                # stays a single compile (and bit-identical to the dense
+                # cache).  ``extra_len`` widens the bound by the tokens
+                # in-flight steps may have advanced past the host mirror.
+                if self.cfg.use_pallas:
+                    max_len = self._live_max_len(pos, active,
+                                                 extra=extra_len)
+            else:
+                table = jnp.zeros((len(toks), 1), jnp.int32)
+            tok_d, pos_d = jnp.asarray(toks), jnp.asarray(pos)
+            counts_d = jnp.asarray(counts, jnp.int32)
+            rem_d = jnp.asarray(remaining)
+            if carry is not None:
+                tok_d, pos_d, counts_d, rem_d = self._advance_decode(
+                    carry["packed"], carry["tok"], carry["pos"],
+                    carry["counts"], carry["remaining"],
+                    jnp.asarray(override), tok_d, pos_d, counts_d, rem_d)
+            args = (self.params, self.cache, tok_d, pos_d,
+                    jnp.asarray(active), table,
+                    jnp.asarray(seeds, jnp.uint32), counts_d,
+                    jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
+                    jnp.asarray(eos), rem_d)
+        with _span("serve.launch"):
+            planned = self._planned.get(("decode", max_len))
+            if planned is not None:
+                self.cache, packed = planned(*args)
+                self.planned_hits += 1
+            else:
+                self.cache, packed = self._decode(*args, max_len=max_len)
         return {"kind": "decode", "packed": packed, "tok": tok_d,
                 "pos": pos_d, "counts": counts_d, "remaining": rem_d,
                 "active": np.asarray(active, bool).copy()}
@@ -1131,7 +1169,8 @@ class ModelRunner:
                     ) -> Tuple[np.ndarray, np.ndarray]:
         """The one host transfer of a dispatched decode step."""
         self._maybe_inject_transfer("decode")
-        host = np.asarray(handle["packed"])        # THE transfer
+        with _span("serve.fetch"):
+            host = np.asarray(handle["packed"])    # THE transfer
         self.decode_transfers += 1
         return host[0], host[1].astype(bool)
 
@@ -1147,32 +1186,34 @@ class ModelRunner:
                       tps, *, carry=None, override=None,
                       extra_len: int = 0) -> Dict[str, Any]:
         """Dispatch one speculative (draft+verify) step; no transfer."""
-        table = self._masked_table(active)
-        # the verify gather bound mirrors the decode-kernel bound; the
-        # jnp path skips it so verify logits stay bitwise-identical to
-        # the single-token decode path (greedy spec == greedy plain)
-        max_len = None
-        if self.cfg.use_pallas:
-            max_len = self._live_max_len(pos, active,
-                                         extra=self.speculate_k + extra_len)
-        tok_d, pos_d = jnp.asarray(toks), jnp.asarray(pos)
-        counts_d = jnp.asarray(counts, jnp.int32)
-        if carry is not None:
-            tok_d, pos_d, counts_d = self._advance_spec(
-                carry["packed"], carry["tok"], carry["pos"],
-                carry["counts"], jnp.asarray(override), tok_d, pos_d,
-                counts_d)
-        args = (self.params, self.draft_params, self.cache,
-                self.draft_cache, tok_d, pos_d, jnp.asarray(active), table,
-                jnp.asarray(seeds, jnp.uint32), counts_d,
-                jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps))
-        planned = self._planned.get(("spec", max_len))
-        if planned is not None:
-            self.cache, self.draft_cache, packed = planned(*args)
-            self.planned_hits += 1
-        else:
-            self.cache, self.draft_cache, packed = self._spec(
-                *args, max_len=max_len)
+        with _span("serve.inputs"):
+            table = self._masked_table(active)
+            # the verify gather bound mirrors the decode-kernel bound; the
+            # jnp path skips it so verify logits stay bitwise-identical
+            # to the single-token decode path (greedy spec == greedy plain)
+            max_len = None
+            if self.cfg.use_pallas:
+                max_len = self._live_max_len(
+                    pos, active, extra=self.speculate_k + extra_len)
+            tok_d, pos_d = jnp.asarray(toks), jnp.asarray(pos)
+            counts_d = jnp.asarray(counts, jnp.int32)
+            if carry is not None:
+                tok_d, pos_d, counts_d = self._advance_spec(
+                    carry["packed"], carry["tok"], carry["pos"],
+                    carry["counts"], jnp.asarray(override), tok_d, pos_d,
+                    counts_d)
+            args = (self.params, self.draft_params, self.cache,
+                    self.draft_cache, tok_d, pos_d, jnp.asarray(active),
+                    table, jnp.asarray(seeds, jnp.uint32), counts_d,
+                    jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps))
+        with _span("serve.launch"):
+            planned = self._planned.get(("spec", max_len))
+            if planned is not None:
+                self.cache, self.draft_cache, packed = planned(*args)
+                self.planned_hits += 1
+            else:
+                self.cache, self.draft_cache, packed = self._spec(
+                    *args, max_len=max_len)
         return {"kind": "spec", "packed": packed, "tok": tok_d,
                 "pos": pos_d, "counts": counts_d,
                 "active": np.asarray(active, bool).copy()}
@@ -1181,7 +1222,8 @@ class ModelRunner:
                   ) -> Tuple[np.ndarray, np.ndarray]:
         """The one host transfer of a dispatched speculative step."""
         self._maybe_inject_transfer("draft_verify")
-        host = np.asarray(handle["packed"])        # THE transfer
+        with _span("serve.fetch"):
+            host = np.asarray(handle["packed"])    # THE transfer
         self.decode_transfers += 1
         return host[:-1].T, host[-1]
 
@@ -1313,6 +1355,8 @@ class Engine:
         self._remaining = np.zeros((B,), np.int32)
         self._seeds = np.zeros((B,), np.uint32)    # per-request PRNG seed
         self._counts = np.zeros((B,), np.int32)    # tokens emitted so far
+        self._emit_work = np.zeros((B,), np.int64)  # chunk + prefill calls
+                                                    # at the last emission
 
         # pipelined stepping (pipeline_depth >= 1): dispatched steps
         # whose packed transfer has not been waited on yet, oldest first.
@@ -1326,7 +1370,6 @@ class Engine:
         self._inflight: deque = deque()
         self._host_fresh = np.ones((B,), bool)
         self._slot_gen = np.zeros((B,), np.int64)
-        self._last_dispatch_t: Optional[float] = None
 
     def capabilities(self) -> Dict[str, Dict[str, Any]]:
         """Unified feature report for this (architecture, engine-config)
@@ -1613,6 +1656,14 @@ class Engine:
 
     # ------------------------------------------------------------------
     def _emit(self, slot: int, req: Request, tok: int) -> None:
+        # a chunk or prefill program that ran since this slot's previous
+        # token delayed this one: count it (the fetch of a chunk blocks
+        # the host, so in the pipelined loop this is the token applied
+        # after the chunk, not the one dispatched behind it)
+        work = self.runner.chunk_calls + self.runner.prefill_calls
+        if req.output and work != self._emit_work[slot]:
+            req.chunk_step_tokens += 1
+        self._emit_work[slot] = work
         req.output.append(tok)
         if req.on_token is not None:
             req.on_token(req, tok)
@@ -1716,18 +1767,20 @@ class Engine:
             req.prefilled = 0
             req.draft_filled = 0
             req.pending_first = None
+            if not req.preemptions:
+                req.t_admitted = 0.0       # its first admission undone
         self.scheduler.queue.extendleft(
             [r for _, r in sorted(rows, key=lambda sr: sr[1].rid,
                                   reverse=True)])
 
-    def _admit(self) -> int:
-        """Admit queued requests into slots.  Returns the number of
-        requests that made prefill progress this round (admission
-        progress, for the stall watchdog)."""
+    def _admit(self) -> List[Tuple[int, Request]]:
+        """Admit queued requests into slots.  Returns the (slot, request)
+        rows that made prefill progress this round (admission progress,
+        for the stall watchdog)."""
         self._preempt_for_admission()
         chunked = self.runner.prefill_chunk > 0
         warm_rows: List[Tuple[int, Request]] = []
-        admitted = 0
+        admitted: List[Tuple[int, Request]] = []
         for bucket, group in self.scheduler.plan_admission(
                 self._make_can_fit()):
             if self.runner.paged:
@@ -1756,10 +1809,13 @@ class Engine:
                 if bounced:
                     self._unadmit(bounced)
                 group = kept
-            admitted += len(group)
+            admitted += group
+            now = time.perf_counter()
             for slot, req in group:
                 if req.output:
                     self.metrics.resumes += 1
+                if not req.t_admitted:
+                    req.t_admitted = now
                 self._temps[slot] = req.params.temperature
                 self._topks[slot] = req.params.top_k
                 self._topps[slot] = req.params.top_p
@@ -1803,7 +1859,6 @@ class Engine:
             except TransferFault:
                 self.metrics.transfer_faults += 1
                 self._unadmit(cold)
-                admitted -= len(cold)
                 continue
             if self.runner.paged:
                 for slot, req in cold:
@@ -1834,13 +1889,15 @@ class Engine:
             except TransferFault:
                 self.metrics.transfer_faults += 1
                 self._unadmit(warm_rows)
-                return admitted - len(warm_rows)
-            for slot, req in warm_rows:
-                self.runner.kv.commit_tokens(slot, req.seq_tokens)
-            for (slot, req), tok in zip(warm_rows, toks):
-                req.prefilled = len(req.seq_tokens)
-                self._start_decode(slot, req, tok)   # per-slot draft fill
-        return admitted
+            else:
+                for slot, req in warm_rows:
+                    self.runner.kv.commit_tokens(slot, req.seq_tokens)
+                for (slot, req), tok in zip(warm_rows, toks):
+                    req.prefilled = len(req.seq_tokens)
+                    self._start_decode(slot, req, tok)   # per-slot draft
+        # rows a transfer fault rolled back are queued again
+        return [(s, r) for s, r in admitted
+                if r.state is not RequestState.QUEUED]
 
     def _prefill_chunks(self) -> int:
         """Advance every prefilling request by one chunk (one batched
@@ -1858,78 +1915,81 @@ class Engine:
                 if r.state is RequestState.PREFILL]
         if not rows:
             return 0
-        spec = self.runner.speculate_k > 0
-        tgt = [(s, r) for s, r in rows
-               if r.prefilled < len(r.seq_tokens)]
-        if tgt:
-            n = len(tgt)
-            toks = np.zeros((n, C), np.int32)
-            pos = np.empty((n,), np.int32)
-            last_idx = np.zeros((n,), np.int32)
-            for i, (slot, req) in enumerate(tgt):
-                seq = req.seq_tokens
-                chunk = seq[req.prefilled:req.prefilled + C]
-                toks[i, :len(chunk)] = chunk
-                pos[i] = req.prefilled
-                last_idx[i] = min(C - 1, len(seq) - 1 - req.prefilled)
-            try:
-                cand = self.runner.chunk(toks, pos, [s for s, _ in tgt],
-                                         last_idx,
-                                         [r.seed for _, r in tgt],
-                                         [len(r.output) for _, r in tgt],
-                                         [r.params for _, r in tgt])
-            except TransferFault:
-                self.metrics.transfer_faults += 1
-                return 0
-            for i, (slot, req) in enumerate(tgt):
-                seq = req.seq_tokens
-                req.prefilled += C
-                if req.prefilled >= len(seq):
-                    req.prefilled = len(seq)
-                    self.runner.kv.commit_tokens(slot, seq)
-                    if spec:
-                        req.pending_first = int(cand[i])
-                    else:
-                        self._start_decode(slot, req, cand[i])
-                else:
-                    # the chunk's writes are in the device stream: its
-                    # full blocks are now matchable by later admissions
-                    self.runner.kv.commit_tokens(slot, seq[:req.prefilled])
-        advanced = len(tgt)
-        if spec:
-            # the drafter fills [0, N) — it has no prefix cache, so its
-            # cursor can trail a prefix-hit target row; pad positions
-            # past the end are causally masked and later overwritten
-            drows = [(s, r) for s, r in rows
-                     if r.draft_filled < len(r.seq_tokens)]
-            if drows:
-                m = len(drows)
-                dtoks = np.zeros((m, C), np.int32)
-                dpos = np.empty((m,), np.int32)
-                for i, (slot, req) in enumerate(drows):
+        with _span("serve.prefill") as sp:
+            spec = self.runner.speculate_k > 0
+            tgt = [(s, r) for s, r in rows
+                   if r.prefilled < len(r.seq_tokens)]
+            if tgt:
+                n = len(tgt)
+                toks = np.zeros((n, C), np.int32)
+                pos = np.empty((n,), np.int32)
+                last_idx = np.zeros((n,), np.int32)
+                for i, (slot, req) in enumerate(tgt):
                     seq = req.seq_tokens
-                    chunk = seq[req.draft_filled:req.draft_filled + C]
-                    dtoks[i, :len(chunk)] = chunk
-                    dpos[i] = req.draft_filled
-                self.runner.draft_chunk(dtoks, dpos,
-                                        [s for s, _ in drows])
-                for slot, req in drows:
-                    req.draft_filled = min(req.draft_filled + C,
-                                           len(req.seq_tokens))
-                advanced = len({s for s, _ in tgt}
-                               | {s for s, _ in drows})
-            # both cursors caught up: release the parked first token
-            # into the decode batch (batch_draft=True — the drafter is
-            # already warm, skip the whole-prompt fill)
-            for slot, req in rows:
-                if (self.scheduler.slots[slot] is req
-                        and req.pending_first is not None
-                        and req.prefilled >= len(req.seq_tokens)
-                        and req.draft_filled >= len(req.seq_tokens)):
-                    tok = req.pending_first
-                    req.pending_first = None
-                    self._start_decode(slot, req, tok, batch_draft=True)
-        return advanced
+                    chunk = seq[req.prefilled:req.prefilled + C]
+                    toks[i, :len(chunk)] = chunk
+                    pos[i] = req.prefilled
+                    last_idx[i] = min(C - 1, len(seq) - 1 - req.prefilled)
+                sp.set_metadata(rows=n, tokens=int(last_idx.sum()) + n,
+                                padded=n * C, rids=_rids(tgt))
+                try:
+                    cand = self.runner.chunk(toks, pos, [s for s, _ in tgt],
+                                             last_idx,
+                                             [r.seed for _, r in tgt],
+                                             [len(r.output) for _, r in tgt],
+                                             [r.params for _, r in tgt])
+                except TransferFault:
+                    self.metrics.transfer_faults += 1
+                    return 0
+                for i, (slot, req) in enumerate(tgt):
+                    seq = req.seq_tokens
+                    req.prefilled += C
+                    if req.prefilled >= len(seq):
+                        req.prefilled = len(seq)
+                        self.runner.kv.commit_tokens(slot, seq)
+                        if spec:
+                            req.pending_first = int(cand[i])
+                        else:
+                            self._start_decode(slot, req, cand[i])
+                    else:
+                        # the chunk's writes are in the device stream: its
+                        # full blocks are now matchable by later admissions
+                        self.runner.kv.commit_tokens(slot, seq[:req.prefilled])
+            advanced = len(tgt)
+            if spec:
+                # the drafter fills [0, N) — it has no prefix cache, so its
+                # cursor can trail a prefix-hit target row; pad positions
+                # past the end are causally masked and later overwritten
+                drows = [(s, r) for s, r in rows
+                         if r.draft_filled < len(r.seq_tokens)]
+                if drows:
+                    m = len(drows)
+                    dtoks = np.zeros((m, C), np.int32)
+                    dpos = np.empty((m,), np.int32)
+                    for i, (slot, req) in enumerate(drows):
+                        seq = req.seq_tokens
+                        chunk = seq[req.draft_filled:req.draft_filled + C]
+                        dtoks[i, :len(chunk)] = chunk
+                        dpos[i] = req.draft_filled
+                    self.runner.draft_chunk(dtoks, dpos,
+                                            [s for s, _ in drows])
+                    for slot, req in drows:
+                        req.draft_filled = min(req.draft_filled + C,
+                                               len(req.seq_tokens))
+                    advanced = len({s for s, _ in tgt}
+                                   | {s for s, _ in drows})
+                # both cursors caught up: release the parked first token
+                # into the decode batch (batch_draft=True — the drafter is
+                # already warm, skip the whole-prompt fill)
+                for slot, req in rows:
+                    if (self.scheduler.slots[slot] is req
+                            and req.pending_first is not None
+                            and req.prefilled >= len(req.seq_tokens)
+                            and req.draft_filled >= len(req.seq_tokens)):
+                        tok = req.pending_first
+                        req.pending_first = None
+                        self._start_decode(slot, req, tok, batch_draft=True)
+            return advanced
 
     # ------------------------------------------------------------------
     def fork(self, parent: Request, n: int, *,
@@ -2009,7 +2069,8 @@ class Engine:
             child.prefilled = len(parent.prompt)
             child.cached_prefix = kv.committed(slot)
             child.truncated = parent.truncated
-            child.t_submit = child.t_first = time.perf_counter()
+            child.t_submit = child.t_admitted = child.t_first = \
+                time.perf_counter()
             child.t_submit_wall = time.time()
             self.scheduler.slots[slot] = child
             self._tok[slot] = self._tok[pslot]
@@ -2037,14 +2098,13 @@ class Engine:
             self.metrics.max_active, len(self.scheduler.active_slots()))
         return children
 
-    def _cow(self, active: List[Tuple[int, Request]],
-             span: Optional[int] = None) -> None:
+    def _cow(self, active: List[Tuple[int, Request]], span: int) -> None:
         """Copy-on-write gate before a decode/verify step: any block a
         slot is about to write while sharing it (fork siblings, live
         prefix-cache readers) is duplicated first, so the other readers
-        keep the original bytes.  ``span`` widens the per-slot write
-        window past the host position mirror — the pipelined loop must
-        cover every position its in-flight steps may still write.
+        keep the original bytes.  ``span`` is the per-slot write window
+        past the host position mirror: K+1 for this step's decode or
+        verify, times the steps in flight (pipelined loop) plus one.
 
         Under block exhaustion (a fork storm about to diverge
         everywhere) the writer preempts equal-or-lower-priority decoders
@@ -2055,8 +2115,6 @@ class Engine:
         Pairs of a writer that got preempted mid-pass are dropped before
         the device copy: its swapped-in blocks returned to the pool, and
         copying into them could race a later writer's reuse."""
-        if span is None:
-            span = self.runner.speculate_k + 1   # verify: pos..pos+K
         slot_pairs: List[Tuple[int, Request,
                                List[Tuple[int, int]]]] = []
         kv = self.runner.kv
@@ -2097,6 +2155,15 @@ class Engine:
                    ) -> List[Tuple[int, Request, int]]:
         return [(s, r, int(self._slot_gen[s])) for s, r in active]
 
+    def _apply(self, rows: List[Tuple[int, Request, int]], out,
+               spec: bool) -> int:
+        """Apply one decode (``out`` = tokens, done) or speculative
+        (tokens [slots, K+1], counts) step."""
+        with _span("serve.emit"):
+            if spec:
+                return self._apply_spec(rows, *out)
+            return self._apply_decode(rows, *out)
+
     def _apply_decode(self, rows: List[Tuple[int, Request, int]],
                       toks, done) -> int:
         n = 0
@@ -2118,6 +2185,9 @@ class Engine:
 
     def _apply_spec(self, rows: List[Tuple[int, Request, int]],
                     toks_mat, counts) -> int:
+        """Every decoding slot advances by 1..K+1 tokens (per-slot
+        variable acceptance).  EOS and the remaining-budget cap are
+        applied here, so a slot never advances past its reservation."""
         acc = prop = n = 0
         K = self.runner.speculate_k
         for slot, req, gen in rows:
@@ -2155,17 +2225,6 @@ class Engine:
         return n
 
     # ------------------------------------------------------------------
-    def _spec_step(self, active: List[Tuple[int, Request]]) -> None:
-        """One synchronous track-speculative step: every decoding slot
-        advances by 1..K+1 tokens (per-slot variable acceptance).  EOS
-        and the remaining-budget cap are applied host-side on the packed
-        result, so a slot never advances past its reservation."""
-        rows = self._snap_rows(active)
-        toks_mat, counts = self.runner.draft_verify(
-            self._tok, self._pos, self._active, self._seeds, self._counts,
-            self._temps, self._topks, self._topps)
-        self._apply_spec(rows, toks_mat, counts)
-
     def step(self) -> int:
         """Expire deadlines, admit queued requests (preempting if a
         higher-priority head is starved), advance prefill chunks, and
@@ -2179,18 +2238,33 @@ class Engine:
         With ``pipeline_depth > 0`` the loop runs asynchronously: step
         N+1 is dispatched before step N's host transfer is waited on,
         and every scheduler decision overlaps device execution."""
-        if self.pipeline_depth > 0:
-            return self._step_pipelined()
-        return self._step_sync()
+        states = [r.state for _, r in self.scheduler.active_slots()]
+        with _span("serve.step", step=self.steps_run,
+                   queued=len(self.scheduler.queue),
+                   prefilling=states.count(RequestState.PREFILL),
+                   decoding=states.count(RequestState.DECODE),
+                   t=time.perf_counter_ns()):
+            if self.pipeline_depth > 0:
+                return self._step_pipelined()
+            return self._step_sync()
 
-    def _step_sync(self) -> int:
-        t0 = time.perf_counter()
+    def _plan_step(self) -> Tuple[int, List[Tuple[int, Request]]]:
+        """The scheduler half of a step, shared by both loops: deadlines,
+        admission, one chunk of every prefilling row, and copy-on-write
+        before the decode (which may preempt).  Returns the progress so
+        far and the decoding rows."""
         if self.faults is not None:
             dt = self.faults.take_slow()
             if dt > 0:
                 time.sleep(dt)         # injected slow step (chaos tests)
         self._expire_deadlines()
-        progress = self._admit()
+        with _span("serve.admit") as sp:
+            admitted = self._admit()
+            sp.set_metadata(admitted=len(admitted),
+                            hit_tokens=sum(r.cached_prefix
+                                           for _, r in admitted),
+                            rids=_rids(admitted))
+        progress = len(admitted)
         if self.runner.prefill_chunk:
             progress += self._prefill_chunks()
         self.metrics.max_active = max(
@@ -2198,20 +2272,34 @@ class Engine:
         active = [(s, r) for s, r in self.scheduler.active_slots()
                   if r.state is RequestState.DECODE]
         if self.runner.paged and active:
-            self._cow(active)          # may preempt: re-filter below
+            # the CoW window covers every position the in-flight steps
+            # plus this one may write past the host mirror
+            with _span("serve.cow"):
+                self._cow(active, (self.runner.speculate_k + 1)
+                          * (len(self._inflight) + 1))
             active = [(s, r) for s, r in active
                       if self.scheduler.slots[s] is r]
+        return progress, active
+
+    def _step_sync(self) -> int:
+        t0 = time.perf_counter()
+        progress, active = self._plan_step()
         if active:
+            rows = self._snap_rows(active)
+            spec = bool(self.runner.speculate_k)
             try:
-                if self.runner.speculate_k:
-                    self._spec_step(active)
-                else:
-                    rows = self._snap_rows(active)
-                    toks, done = self.runner.decode(
-                        self._tok, self._pos, self._active, self._seeds,
-                        self._counts, self._temps, self._topks,
-                        self._topps, self._eos, self._remaining)
-                    self._apply_decode(rows, toks, done)
+                with _span("serve.decode", rids=_rids(active)):
+                    if spec:
+                        out = self.runner.draft_verify(
+                            self._tok, self._pos, self._active, self._seeds,
+                            self._counts, self._temps, self._topks,
+                            self._topps)
+                    else:
+                        out = self.runner.decode(
+                            self._tok, self._pos, self._active, self._seeds,
+                            self._counts, self._temps, self._topks,
+                            self._topps, self._eos, self._remaining)
+                self._apply(rows, out, spec)
                 progress += len(active)
             except TransferFault:
                 self.metrics.transfer_faults += 1
@@ -2262,10 +2350,6 @@ class Engine:
                 self._counts, self._temps, self._topks, self._topps,
                 self._eos, self._remaining, carry=carry,
                 override=override, extra_len=extra)
-        now = time.perf_counter()
-        if self._last_dispatch_t is not None:
-            self.metrics.dispatch_gaps.append(now - self._last_dispatch_t)
-        self._last_dispatch_t = now
         self._inflight.append({"handle": handle, "rows": rows,
                                "spec": spec})
         self.metrics.steps_in_flight = max(self.metrics.steps_in_flight,
@@ -2283,17 +2367,14 @@ class Engine:
         returns -1.  Otherwise returns the number of rows applied."""
         entry = self._inflight[0]
         try:
-            if entry["spec"]:
-                toks_mat, counts = self.runner.wait_spec(entry["handle"])
-            else:
-                toks, done = self.runner.wait_decode(entry["handle"])
+            wait = (self.runner.wait_spec if entry["spec"]
+                    else self.runner.wait_decode)
+            out = wait(entry["handle"])
         except TransferFault:
             self.metrics.transfer_faults += 1
             return -1
         self._inflight.popleft()
-        if entry["spec"]:
-            return self._apply_spec(entry["rows"], toks_mat, counts)
-        return self._apply_decode(entry["rows"], toks, done)
+        return self._apply(entry["rows"], out, entry["spec"])
 
     def _drain_inflight(self) -> None:
         """Apply every in-flight step (fork and shutdown paths need
@@ -2315,31 +2396,13 @@ class Engine:
         oldest in-flight transfer waited on.  Nothing happens between
         dispatch and wait, so the device never idles on host work."""
         t0 = time.perf_counter()
-        if self.faults is not None:
-            dt = self.faults.take_slow()
-            if dt > 0:
-                time.sleep(dt)         # injected slow step (chaos tests)
-        self._expire_deadlines()
-        progress = self._admit()
-        if self.runner.prefill_chunk:
-            progress += self._prefill_chunks()
-        self.metrics.max_active = max(
-            self.metrics.max_active, len(self.scheduler.active_slots()))
-        active = [(s, r) for s, r in self.scheduler.active_slots()
-                  if r.state is RequestState.DECODE]
-        if self.runner.paged and active:
-            # widen the CoW window to every position the in-flight
-            # steps plus this one may write past the host mirror
-            span = ((self.runner.speculate_k + 1)
-                    * (len(self._inflight) + 1))
-            self._cow(active, span=span)
-            active = [(s, r) for s, r in active
-                      if self.scheduler.slots[s] is r]
+        progress, active = self._plan_step()
         dispatched = False
         # backpressure: a transfer-fault retry keeps the queue over
         # depth — don't dispatch on top of it, drain first
         if active and len(self._inflight) <= self.pipeline_depth:
-            self._dispatch(active)
+            with _span("serve.decode", rids=_rids(active)):
+                self._dispatch(active)
             dispatched = True
             progress += len(active)    # a dispatched step IS forward
                                        # progress: the watchdog must not

@@ -160,6 +160,7 @@ def sample_batched(logits: jax.Array, key: jax.Array,
     return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
+@jax.named_scope("sample")
 def sample_rows(logits: jax.Array, keys: jax.Array,
                 temperature: jax.Array, top_k: jax.Array,
                 top_p: jax.Array) -> jax.Array:
@@ -170,6 +171,7 @@ def sample_rows(logits: jax.Array, keys: jax.Array,
     return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
+@jax.named_scope("sample")
 def sample_step(logits: jax.Array, keys: jax.Array, temperature: jax.Array,
                 top_k: jax.Array, top_p: jax.Array, active: jax.Array,
                 eos: jax.Array, remaining: jax.Array) -> jax.Array:
@@ -258,6 +260,7 @@ def _filtered_probs(logits: jax.Array, temperature: jax.Array,
     return jnp.where((temperature <= 0.0)[:, None], greedy, probs)
 
 
+@jax.named_scope("sample")
 def accept_step(target_logits: jax.Array, draft_logits: jax.Array,
                 draft_toks: jax.Array, seeds: jax.Array,
                 counters: jax.Array, temperature: jax.Array,
