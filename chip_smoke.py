@@ -179,7 +179,9 @@ def run_engine(cfg, seed: int, dev) -> None:
         f"({sorted({str(l.dtype) for l in leaves})}) on {dev.device_kind}")
     eng = serve.build_engine(args, cfg, params)
     if cfg.use_pallas:
-        fail("the engine was expected on its jnp attention path")
+        fail("the engine was expected with use_pallas off")
+    if eng.runner.decode_attn != "paged_kernel":
+        fail(f"paged decode took {eng.runner.decode_attn!r}, not the kernel")
 
     # a first round with other prompts compiles every program the
     # measured round runs

@@ -303,9 +303,9 @@ def aot_compile(jitted, *args, **static_kwargs):
 
     This is the serving engine's per-bucket "capture once, replay"
     program cache (the CUDA-graph-per-batch-size pattern): the runner
-    plans one decode/spec executable per ``max_len`` bucket at startup
-    and dispatches through the plan, falling back to the ``jax.jit``
-    wrapper for unplanned shapes."""
+    plans the decode executable, and one spec executable per ``max_len``
+    bucket, at startup and dispatches through the plan, falling back to
+    the ``jax.jit`` wrapper for unplanned shapes."""
     structs = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype)
         if not hasattr(x, "shape") or not hasattr(x, "dtype")

@@ -282,13 +282,10 @@ def attention_decode(params, x: jax.Array, cache: Tuple[jax.Array, jax.Array],
                      *, spec: LayerSpec, cfg: ModelConfig,
                      pos: jax.Array, par: Parallelism = NO_PARALLEL,
                      block_table: Optional[jax.Array] = None,
-                     kv_max_len: Optional[int] = None,
                      active: Optional[jax.Array] = None):
     """x: [B, 1, d]; cache k/v: [B, S_cache, KH, hd] dense, or ``PagedLeaf``
     block pools [N, bs, KH, hd] addressed through ``block_table``; pos: [B]
-    int32 (index of the new token).  ``kv_max_len`` (static, host-known
-    upper bound on pos+1) lets the paged kernel skip dead blocks.
-    Returns (out [B,1,d], updated cache).
+    int32 (index of the new token).  Returns (out [B,1,d], updated cache).
 
     For windowed layers the cache is a ring buffer (S_cache == window) and
     the new k/v is written at slot pos % W; otherwise at slot pos (for a
@@ -298,7 +295,8 @@ def attention_decode(params, x: jax.Array, cache: Tuple[jax.Array, jax.Array],
     lanes: paged leaves route inactive lanes to the trash block via the
     masked block table, but ring/state leaves are per-slot arrays with no
     trash row, and a slot mid-chunked-prefill must not have its ring
-    mutated by decode steps of the surrounding batch.
+    mutated by decode steps of the surrounding batch.  The paged kernel
+    reads nothing for an inactive lane.
     """
     B = x.shape[0]
     positions = pos[:, None]                          # [B,1]
@@ -312,7 +310,7 @@ def attention_decode(params, x: jax.Array, cache: Tuple[jax.Array, jax.Array],
         return _paged_decode(params, q, k_new[:, 0], v_new[:, 0],
                              k_cache, v_cache, spec=spec, cfg=cfg, pos=pos,
                              par=par, block_table=block_table,
-                             kv_max_len=kv_max_len, out_dtype=x.dtype)
+                             active=active, out_dtype=x.dtype)
     S_cache = k_cache.shape[1]
     KH = k_cache.shape[2]
     G = H // KH
@@ -369,9 +367,39 @@ def _scatter_cache(cache: jax.Array, new: jax.Array, slot: jax.Array,
 # paged decode / chunked prefill (block-pool caches)
 # ---------------------------------------------------------------------------
 
+@jax.custom_batching.custom_vmap
+def _set_rows(flat: jax.Array, idx: jax.Array, rows: jax.Array) -> jax.Array:
+    """``flat.at[idx].set(rows)``, whose batching rule keeps a mapped
+    axis (PT's tracks) major: with ``idx`` shared, the mapped rows are
+    addressed as rows of one flat array, so the pool keeps the
+    row-major layout the paged kernel reads.  A scatter batched the
+    usual way takes the mapped axis into its window, and the chip's
+    compiler then lays each pool out with that axis next to the head
+    dim, at the price of a relayout of the whole pool every layer."""
+    return flat.at[idx].set(rows)
+
+
+@_set_rows.def_vmap
+def _set_rows_folded(axis_size, in_batched, flat, idx, rows):
+    if in_batched[1]:
+        return jax.vmap(lambda f, i, r: f.at[i].set(r),
+                        in_axes=(0 if in_batched[0] else None, 0,
+                                 0 if in_batched[2] else None))(
+            flat, idx, rows), True
+    if not in_batched[0]:
+        flat = jnp.broadcast_to(flat, (axis_size,) + flat.shape)
+    if not in_batched[2]:
+        rows = jnp.broadcast_to(rows, (axis_size,) + rows.shape)
+    m = flat.shape[1]
+    at = (jnp.arange(axis_size, dtype=idx.dtype)[:, None] * m + idx[None])
+    out = _set_rows(flat.reshape((-1,) + flat.shape[2:]), at.reshape(-1),
+                    rows.reshape((-1,) + rows.shape[2:]))
+    return out.reshape(flat.shape), True
+
+
 @jax.named_scope("kv_write")
-def pool_write(leaf: PagedLeaf, rows: jax.Array,
-               w_idx: jax.Array) -> PagedLeaf:
+def pool_write(leaf: PagedLeaf, rows: jax.Array, w_idx: jax.Array, *,
+               major: bool = False) -> PagedLeaf:
     """Scatter new rows into one pool leaf at flat pool rows ``w_idx``.
 
     ``rows`` has leading dims matching ``w_idx`` and trailing dims equal
@@ -379,13 +407,14 @@ def pool_write(leaf: PagedLeaf, rows: jax.Array,
     latent pools.  An int8 leaf (``scale is not None``) quantizes each
     row over its last axis and scatters payload + scale through the same
     indices.  Layout-polymorphic: any pageable leaf kind goes through
-    here."""
+    here.  ``major`` keeps a mapped axis major in the pool's layout
+    (``_set_rows``), for the paged kernel's reads."""
     idx = w_idx.reshape(-1)
 
     def put(pool, r):
         flat = pool.reshape((-1,) + pool.shape[2:])
-        flat = flat.at[idx].set(
-            r.astype(flat.dtype).reshape((-1,) + pool.shape[2:]))
+        r = r.astype(flat.dtype).reshape((-1,) + pool.shape[2:])
+        flat = _set_rows(flat, idx, r) if major else flat.at[idx].set(r)
         return flat.reshape(pool.shape)
 
     if leaf.scale is not None:
@@ -395,9 +424,10 @@ def pool_write(leaf: PagedLeaf, rows: jax.Array,
 
 
 def _paged_write(k_leaf: PagedLeaf, v_leaf: PagedLeaf, k_new: jax.Array,
-                 v_new: jax.Array, w_idx: jax.Array):
+                 v_new: jax.Array, w_idx: jax.Array, *, major: bool = False):
     """Scatter new K/V rows into pool leaves at pool rows ``w_idx``."""
-    return pool_write(k_leaf, k_new, w_idx), pool_write(v_leaf, v_new, w_idx)
+    return (pool_write(k_leaf, k_new, w_idx, major=major),
+            pool_write(v_leaf, v_new, w_idx, major=major))
 
 
 @jax.named_scope("kv_gather")
@@ -447,10 +477,26 @@ def _paged_read(k_leaf: PagedLeaf, v_leaf: PagedLeaf,
     return k_g, v_g
 
 
+def paged_decode_kernel(cfg: ModelConfig, spec: LayerSpec,
+                        par: Parallelism, quantized: bool) -> bool:
+    """Whether paged decode streams each slot's live pool blocks through
+    the Pallas kernel rather than gathering every slot's full capacity.
+
+    On a TPU the kernel is the path, except for int8 pools, whose
+    one-lane scale pages do not tile for the chip's compiler; elsewhere
+    ``cfg.use_pallas`` asks for it (the interpreter, for tests).  Not
+    under a mesh, which no kernel supports yet, nor with a logit
+    softcap, which the kernel does not apply."""
+    from repro.kernels import ops as kops
+    wanted = not quantized if kops.on_tpu() else cfg.use_pallas
+    return (wanted and par.mesh is None
+            and spec.attn_logit_softcap is None)
+
+
 def _paged_decode(params, q, k_new, v_new, k_leaf: PagedLeaf,
                   v_leaf: PagedLeaf, *, spec: LayerSpec, cfg: ModelConfig,
                   pos: jax.Array, par: Parallelism,
-                  block_table: jax.Array, kv_max_len: Optional[int],
+                  block_table: jax.Array, active: Optional[jax.Array],
                   out_dtype):
     """Decode step against block pools.  q: [B,H,hd]; k_new/v_new:
     [B,KH,hd]; pools [N, bs, KH, hd]; block_table [B, max_blocks_per_seq].
@@ -458,9 +504,12 @@ def _paged_decode(params, q, k_new, v_new, k_leaf: PagedLeaf,
     Only full-attention leaves are ever paged (rings stay dense; a
     windowed layer is paged only when its window covers engine capacity,
     where the window mask is vacuous for every reachable position), so the
-    causal mask j <= pos is the whole story.  The jnp path gathers the
-    same [B, S, KH, hd] view the dense cache stores and runs the identical
-    grouped-GQA einsum — bit-for-bit equal to the dense decode path.
+    causal mask j <= pos is the whole story.  The kernel
+    (``paged_decode_kernel``) reads each active slot's pos + 1 live
+    tokens and nothing for an inactive one, in one program for every
+    length.  The jnp path gathers the same [B, S, KH, hd] view the dense
+    cache stores and runs the identical grouped-GQA einsum — bit-for-bit
+    equal to the dense decode path.
     """
     if block_table is None:
         raise ValueError("paged cache leaf but no block_table passed")
@@ -469,16 +518,17 @@ def _paged_decode(params, q, k_new, v_new, k_leaf: PagedLeaf,
     KH = k_leaf.pool.shape[2]
     G = H // KH
     w_idx = token_to_pool(block_table, pos[:, None], bs)[:, 0]
-    k_leaf, v_leaf = _paged_write(k_leaf, v_leaf, k_new, v_new, w_idx)
+    kernel = paged_decode_kernel(cfg, spec, par, k_leaf.scale is not None)
+    k_leaf, v_leaf = _paged_write(k_leaf, v_leaf, k_new, v_new, w_idx,
+                                  major=kernel)
     new_cache = (k_leaf, v_leaf)
-    if cfg.use_pallas and par.mesh is None and spec.attn_logit_softcap is None:
+    if kernel:
         from repro.kernels import ops as kops
-        # kv_max_len truncates the block sweep to the live prefix: a
-        # short batch never DMAs the dead tail of the pool; int8 pools
-        # ship their scale pools for in-kernel dequant
+        lengths = pos + 1
+        if active is not None:
+            lengths = jnp.where(active, lengths, 0)
         ctx = kops.paged_decode_attention(
-            q, k_leaf.pool, v_leaf.pool,
-            block_table, pos + 1, max_len=kv_max_len,
+            q, k_leaf.pool, v_leaf.pool, block_table, lengths,
             k_scale=k_leaf.scale, v_scale=v_leaf.scale)
     else:
         k_g, v_g = _paged_read(k_leaf, v_leaf, block_table, bs, par)

@@ -99,7 +99,6 @@ def _mixer(params, h, *, cfg, spec, mode, positions, pos, cache, par,
             return attn.attention_decode(params, h, cache, spec=spec,
                                          cfg=cfg, pos=pos, par=par,
                                          block_table=block_table,
-                                         kv_max_len=kv_max_len,
                                          active=active)
         if mode == "chunk":
             return attn.attention_chunk(params, h, cache, spec=spec,

@@ -105,6 +105,7 @@ from repro.common.paged import PagedLeaf, is_paged, wrap_paged
 from repro.common.types import ModelConfig
 from repro.core import track as pt_lib
 from repro.launch import steps as steps_lib
+from repro.models.attention import paged_decode_kernel
 from repro.runtime.parallel import NO_PARALLEL
 from repro.serving.cache import (PagedKVCache, batch_axes, insert_rows,
                                  paged_insert_rows)
@@ -558,6 +559,15 @@ class ModelRunner:
         # (stale rows from the slot's previous tenant) and physical row
         # copies on fork (the block table shares only pool leaves)
         self.has_dense_leaves = self.paged and not self.kv.all_pageable
+        # how paged decode attention reads the pool (``serve.launch``'s
+        # ``attn``): a GQA layer paged at full capacity (a window below
+        # it keeps a dense ring) streams its live blocks through the
+        # kernel where ``paged_decode_kernel`` allows, else gathers
+        self.decode_attn = "paged_kernel" if self.paged and any(
+            s.mixer == "gqa"
+            and (s.window is None or s.window >= max_seq_len)
+            and paged_decode_kernel(cfg, s, par, self.kv_dtype == "int8")
+            for s in map(cfg.spec, cfg.layer_names)) else "gather"
 
         # chunked prefill feeds the prompt through the cache with
         # multi-token decode-style steps; paged leaves append through the
@@ -653,6 +663,8 @@ class ModelRunner:
         self.prefill_shapes: set = set()   # observed (n_reqs, bucket)
         self.chunk_shapes: set = set()     # observed (n_reqs, chunk)
         self.decode_transfers = 0          # host transfers in decode steps
+        self.decode_kernel_calls = 0       # decode calls on the kernel
+        self.decode_live_blocks = 0        # pool blocks they streamed
         self.prefill_calls = 0             # bucketed prefill forwards
         self.chunk_calls = 0               # chunk forwards (incl. warm tails)
 
@@ -728,7 +740,10 @@ class ModelRunner:
         device.  Returns (cache, packed [2, slots] int32 = (token, done)).
         ``active`` threads into the model so dense (ring/state) rows of
         lanes that are idle or mid-chunked-prefill stay frozen — paged
-        leaves are protected by the zeroed table rows instead."""
+        leaves are protected by the zeroed table rows instead.
+        ``max_len`` (static) would bound the MLA latent gather to a live
+        prefix; dispatch passes none, so one decode program serves every
+        live length."""
         if self.paged:
             logits, cache = self.fns["decode"](params, cache, toks, pos,
                                                self.cfg, self.par,
@@ -1120,25 +1135,22 @@ class ModelRunner:
 
     def dispatch_decode(self, toks, pos, active, seeds, counts, temps,
                         tks, tps, eos, remaining, *, carry=None,
-                        override=None, extra_len: int = 0
-                        ) -> Dict[str, Any]:
-        """Dispatch one decode step; no host transfer happens here."""
+                        override=None) -> Dict[str, Any]:
+        """Dispatch one decode step; no host transfer happens here.
+
+        One decode program serves every live length: the paged kernel
+        reads the lengths at run time, and the gather reads the whole
+        capacity.  ``serve.launch`` names the path (``attn``) and, for
+        the kernel, the pool blocks the call streams (``live_blocks``,
+        from the host's positions, which lag in-flight steps) of the
+        ``blocks`` the gather would read."""
         with _span("serve.inputs"):
-            max_len = None
             if self.paged:
                 # lanes not actively decoding (idle, or mid-chunked-
                 # prefill) get zeroed table rows: their stale-position
                 # writes land in the trash block, never in blocks owned
                 # by live requests.
                 table = self._masked_table(active)
-                # the paged kernel sweeps only the live blocks.  Only the
-                # Pallas path consumes the bound — the jnp reference path
-                # stays a single compile (and bit-identical to the dense
-                # cache).  ``extra_len`` widens the bound by the tokens
-                # in-flight steps may have advanced past the host mirror.
-                if self.cfg.use_pallas:
-                    max_len = self._live_max_len(pos, active,
-                                                 extra=extra_len)
             else:
                 table = jnp.zeros((len(toks), 1), jnp.int32)
             tok_d, pos_d = jnp.asarray(toks), jnp.asarray(pos)
@@ -1154,13 +1166,22 @@ class ModelRunner:
                     jnp.asarray(seeds, jnp.uint32), counts_d,
                     jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
                     jnp.asarray(eos), rem_d)
-        with _span("serve.launch"):
-            planned = self._planned.get(("decode", max_len))
+            span = {"attn": self.decode_attn}
+            if self.decode_attn == "paged_kernel":
+                act = np.asarray(active, bool)
+                live = int(np.sum(-(-(np.asarray(pos)[act] + 1)
+                                    // self.kv.block_size)))
+                span.update(live_blocks=live, blocks=self.max_slots
+                            * self.kv.blocks_per_seq)
+                self.decode_kernel_calls += 1
+                self.decode_live_blocks += live
+        with _span("serve.launch", **span):
+            planned = self._planned.get(("decode", None))
             if planned is not None:
                 self.cache, packed = planned(*args)
                 self.planned_hits += 1
             else:
-                self.cache, packed = self._decode(*args, max_len=max_len)
+                self.cache, packed = self._decode(*args)
         return {"kind": "decode", "packed": packed, "tok": tok_d,
                 "pos": pos_d, "counts": counts_d, "remaining": rem_d,
                 "active": np.asarray(active, bool).copy()}
@@ -1188,9 +1209,10 @@ class ModelRunner:
         """Dispatch one speculative (draft+verify) step; no transfer."""
         with _span("serve.inputs"):
             table = self._masked_table(active)
-            # the verify gather bound mirrors the decode-kernel bound; the
-            # jnp path skips it so verify logits stay bitwise-identical
-            # to the single-token decode path (greedy spec == greedy plain)
+            # under ``use_pallas`` the verify gather reads only the live
+            # prefix; otherwise it reads the whole capacity, as the jnp
+            # decode does, so verify logits stay bitwise-identical to the
+            # single-token decode path (greedy spec == greedy plain)
             max_len = None
             if self.cfg.use_pallas:
                 max_len = self._live_max_len(
@@ -1237,8 +1259,9 @@ class ModelRunner:
 
     def plan_programs(self) -> int:
         """Pre-plan the steady-state step programs: AOT-lower and
-        compile one decode (and, when speculating, one spec) executable
-        per ``max_len`` bucket, so dispatch replays a ready program with
+        compile the decode executable (and, when speculating, one spec
+        executable per ``max_len`` bucket), so dispatch replays a ready
+        program with
         the tracer entirely off the hot path — the CUDA-graph-per-
         batch-size pattern of flashinfer-style runners.  Dispatch falls
         back to the ``jax.jit`` wrapper for any unplanned shape.
@@ -1258,20 +1281,19 @@ class ModelRunner:
             table = jnp.zeros_like(jnp.asarray(self.kv.table_np))
         else:
             table = jnp.zeros((B, 1), jnp.int32)
-        # one variant per power-of-two live-block bound (pallas), else
-        # the single ``None`` variant the jnp path uses
+        if ("decode", None) not in self._planned:
+            self._planned[("decode", None)] = steps_lib.aot_compile(
+                self._decode, self.params, self.cache, toks, pos, active,
+                table, seeds, counts, temps, tks, tps, eos, rem)
+        # the verify gather: one variant per power-of-two live-block
+        # bound (pallas), else the single ``None`` variant
         variants: List[Optional[int]] = [None]
-        if self.paged and self.cfg.use_pallas:
+        if self.speculate_k and self.cfg.use_pallas:
             bs, p2 = self.kv.block_size, 1
             while p2 <= self.kv.blocks_per_seq:
                 variants.append(p2 * bs)
                 p2 *= 2
         for max_len in variants:
-            if ("decode", max_len) not in self._planned:
-                self._planned[("decode", max_len)] = steps_lib.aot_compile(
-                    self._decode, self.params, self.cache, toks, pos,
-                    active, table, seeds, counts, temps, tks, tps, eos,
-                    rem, max_len=max_len)
             if self.speculate_k and ("spec", max_len) not in self._planned:
                 self._planned[("spec", max_len)] = steps_lib.aot_compile(
                     self._spec, self.params, self.draft_params, self.cache,
@@ -2335,21 +2357,21 @@ class Engine:
                     else self._host_fresh | ~carry["active"])
         rows = self._snap_rows(active)
         spec = bool(self.runner.speculate_k)
-        # the host position mirror lags the device by the in-flight
-        # depth: widen the kernel's live-length bound to cover it
-        extra = len(self._inflight)
         if spec:
+            # the host position mirror lags the device by the in-flight
+            # depth: widen the verify gather's live-length bound to it
             handle = self.runner.dispatch_spec(
                 self._tok, self._pos, self._active, self._seeds,
                 self._counts, self._temps, self._topks, self._topps,
                 carry=carry, override=override,
-                extra_len=(self.runner.speculate_k + 1) * extra)
+                extra_len=(self.runner.speculate_k + 1)
+                * len(self._inflight))
         else:
             handle = self.runner.dispatch_decode(
                 self._tok, self._pos, self._active, self._seeds,
                 self._counts, self._temps, self._topks, self._topps,
                 self._eos, self._remaining, carry=carry,
-                override=override, extra_len=extra)
+                override=override)
         self._inflight.append({"handle": handle, "rows": rows,
                                "spec": spec})
         self.metrics.steps_in_flight = max(self.metrics.steps_in_flight,

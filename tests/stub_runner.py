@@ -209,8 +209,7 @@ class StubRunner:
 
     def dispatch_decode(self, toks, pos, active, seeds, counts, temps,
                         tks, tps, eos, remaining, *, carry=None,
-                        override=None, extra_len: int = 0
-                        ) -> Dict[str, Any]:
+                        override=None) -> Dict[str, Any]:
         act = np.asarray(active, bool).copy()
         eff_counts = np.asarray(counts, np.int64).copy()
         eff_rem = np.asarray(remaining, np.int64).copy()

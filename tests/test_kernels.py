@@ -1,11 +1,14 @@
 """Pallas-kernel sweeps: shapes × dtypes, assert_allclose vs the ref.py
 pure-jnp oracles (interpret=True executes the kernel body on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.common.quant import quantize_rows
+from repro.kernels import decode_attention as da
 from repro.kernels import ops
 from repro.kernels import ref
 
@@ -66,36 +69,74 @@ def test_decode_attention_sweep(B, S, KH, G, hd, dtype):
                                np.asarray(r, np.float32), rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("B,KH,G,hd,bs,nmax", [(2, 2, 2, 32, 16, 4),
-                                               (1, 1, 4, 64, 8, 8),
-                                               (3, 4, 1, 128, 32, 2)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention_sweep(B, KH, G, hd, bs, nmax, dtype):
-    """The paged kernel streams KV blocks through a scalar-prefetched
-    block table; outputs must match the gather-then-dense oracle for
-    random (shuffled, shared-pool) tables and ragged lengths."""
-    H = KH * G
+def _paged_case(B, KH, G, hd, bs, nmax, tracks, dtype):
+    """q, pools and a shuffled table over a shared pool for the paged
+    kernel, with ragged lengths: the full capacity, then 0 (an inactive
+    lane), 1, an exact compute-block boundary and a mid-block length,
+    as far as B goes.  Table entries past a slot's length are garbage
+    (``table``); ``clean`` holds valid ids there, for the oracle.
+    ``tracks`` > 0 adds a leading axis of that many tracks to q and the
+    pools, as PT's track vmap holds them."""
+    lead = (tracks,) if tracks else ()
     N = B * nmax + 1                     # pool with spare blocks + trash
-    q = _rand(0, (B, H, hd), dtype)
-    k_pool = _rand(1, (N, bs, KH, hd), dtype)
-    v_pool = _rand(2, (N, bs, KH, hd), dtype)
+    q = _rand(0, lead + (B, KH * G, hd), dtype)
+    k_pool = _rand(1, lead + (N, bs, KH, hd), dtype)
+    v_pool = _rand(2, lead + (N, bs, KH, hd), dtype)
     rng = np.random.default_rng(7)
     # each row draws distinct blocks from the shared pool, shuffled
-    perm = rng.permutation(N - 1)[:B * nmax].reshape(B, nmax) + 1
-    table = jnp.asarray(perm, jnp.int32)
-    lengths = jnp.asarray(
-        [1 + (11 * i + 5) % (nmax * bs) for i in range(B)], jnp.int32)
-    o = ops.paged_decode_attention(q, k_pool, v_pool, table, lengths)
-    r = ref.paged_decode_attention_ref(q, k_pool, v_pool, table, lengths)
-    tol = _TOL[dtype]
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(r, np.float32), rtol=tol, atol=tol)
-    # max_len truncates the block sweep without changing results
-    ml = int(lengths.max())
-    o2 = ops.paged_decode_attention(q, k_pool, v_pool, table, lengths,
-                                    max_len=ml)
-    np.testing.assert_allclose(np.asarray(o2, np.float32),
-                               np.asarray(r, np.float32), rtol=tol, atol=tol)
+    clean = rng.permutation(N - 1)[:B * nmax].reshape(B, nmax) + 1
+    cap = nmax * bs
+    block = min(nmax, da.MAX_PAGES,
+                da.BLOCK_COLS // (max(tracks, 1) * KH * bs)) * bs
+    lengths = np.asarray([cap, 0, 1, block, cap // 2 + 3][:B], np.int32)
+    live = np.arange(nmax)[None] * bs < lengths[:, None]
+    table = np.where(live, clean, rng.integers(-9, 10 ** 6, clean.shape))
+    return (q, k_pool, v_pool, jnp.asarray(table, jnp.int32),
+            jnp.asarray(clean, jnp.int32), jnp.asarray(lengths))
+
+
+def _check_paged(o, r, lengths, tol):
+    """Lanes with KV match the oracle; a lane of length 0 reads nothing
+    and returns zeros (the oracle's softmax over no key is undefined)."""
+    o, r = np.asarray(o, np.float32), np.asarray(r, np.float32)
+    on = np.asarray(lengths) > 0
+    np.testing.assert_allclose(o[..., on, :, :], r[..., on, :, :],
+                               rtol=tol, atol=tol)
+    assert not o[..., ~on, :, :].any()
+
+
+def _paged_call(tracks, table, lengths, **scales):
+    """The kernel and its oracle, vmapped over tracks as
+    ``core.track._track_layers`` calls them (table and lengths shared)."""
+    def call(q, k, v, *sc):
+        kw = dict(zip(("k_scale", "v_scale"), sc))
+        return ops.paged_decode_attention(q, k, v, table, lengths, **kw)
+    return jax.vmap(call) if tracks else call
+
+
+# (B, KH, G, hd, bs, nmax, tracks): small GQA shapes; PT's tracks folded
+# into the head axis (8 tracks x 1 KV head, G 4, hd 128, under the
+# track vmap); the dense-6b layout (8 KV heads).  The last two span
+# three compute blocks of 256 tokens a slot.
+PAGED_CASES = [(2, 2, 2, 32, 16, 4, 0), (1, 1, 4, 64, 8, 8, 0),
+               (3, 4, 1, 128, 32, 2, 0), (5, 1, 4, 128, 16, 40, 8),
+               (5, 8, 4, 128, 16, 40, 0)]
+
+
+@pytest.mark.parametrize("B,KH,G,hd,bs,nmax,tracks", PAGED_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_attention_sweep(B, KH, G, hd, bs, nmax, tracks, dtype):
+    """The paged kernel streams KV blocks through a scalar-prefetched
+    block table; outputs must match the gather-then-dense oracle for
+    random (shuffled, shared-pool) tables, ragged lengths and garbage
+    table entries past each slot's length."""
+    q, k_pool, v_pool, table, clean, lengths = _paged_case(
+        B, KH, G, hd, bs, nmax, tracks, dtype)
+    o = _paged_call(tracks, table, lengths)(q, k_pool, v_pool)
+    oracle = functools.partial(ref.paged_decode_attention_ref,
+                               block_table=clean, lengths=lengths)
+    r = (jax.vmap(oracle) if tracks else oracle)(q, k_pool, v_pool)
+    _check_paged(o, r, lengths, _TOL[dtype])
 
 
 @pytest.mark.parametrize("B,S,KH,G,hd", [(2, 256, 2, 2, 32),
@@ -119,35 +160,21 @@ def test_decode_attention_int8_sweep(B, S, KH, G, hd):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("B,KH,G,hd,bs,nmax", [(2, 2, 2, 32, 16, 4),
-                                               (1, 1, 4, 64, 8, 8)])
-def test_paged_decode_attention_int8_sweep(B, KH, G, hd, bs, nmax):
+@pytest.mark.parametrize("B,KH,G,hd,bs,nmax,tracks",
+                         PAGED_CASES[:2] + PAGED_CASES[3:])
+def test_paged_decode_attention_int8_sweep(B, KH, G, hd, bs, nmax, tracks):
     """int8 block pools + scale pools riding the same scalar-prefetched
-    block table: matches the oracle on the dequantized pool, with and
-    without the max_len sweep bound."""
-    H = KH * G
-    N = B * nmax + 1
-    q = _rand(0, (B, H, hd), jnp.float32)
-    k_pool = _rand(1, (N, bs, KH, hd), jnp.float32)
-    v_pool = _rand(2, (N, bs, KH, hd), jnp.float32)
+    block table: matches the oracle on the dequantized pool, for ragged
+    lengths and garbage table entries past each slot's length."""
+    q, k_pool, v_pool, table, clean, lengths = _paged_case(
+        B, KH, G, hd, bs, nmax, tracks, jnp.float32)
     kq, ks = quantize_rows(k_pool)
     vq, vs = quantize_rows(v_pool)
-    rng = np.random.default_rng(7)
-    perm = rng.permutation(N - 1)[:B * nmax].reshape(B, nmax) + 1
-    table = jnp.asarray(perm, jnp.int32)
-    lengths = jnp.asarray(
-        [1 + (11 * i + 5) % (nmax * bs) for i in range(B)], jnp.int32)
-    o = ops.paged_decode_attention(q, kq, vq, table, lengths,
-                                   k_scale=ks, v_scale=vs)
-    r = ref.paged_decode_attention_ref(q, _dq(kq, ks), _dq(vq, vs),
-                                       table, lengths)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(r),
-                               rtol=2e-5, atol=2e-5)
-    o2 = ops.paged_decode_attention(q, kq, vq, table, lengths,
-                                    k_scale=ks, v_scale=vs,
-                                    max_len=int(lengths.max()))
-    np.testing.assert_allclose(np.asarray(o2), np.asarray(r),
-                               rtol=2e-5, atol=2e-5)
+    o = _paged_call(tracks, table, lengths)(q, kq, vq, ks, vs)
+    oracle = functools.partial(ref.paged_decode_attention_ref,
+                               block_table=clean, lengths=lengths)
+    r = (jax.vmap(oracle) if tracks else oracle)(q, _dq(kq, ks), _dq(vq, vs))
+    _check_paged(o, r, lengths, 2e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -216,17 +243,20 @@ def test_paged_matches_contiguous_identity_table():
 
 
 def test_decode_attention_max_len_skips_dead_blocks():
-    """Truncating the sequential grid to the max valid length must not
-    change the result (the skipped blocks are fully masked anyway)."""
+    """Blocks past a slot's length are neither copied nor computed: NaN
+    in every dead block of the cache leaves the result equal to the
+    oracle's on the clean cache."""
     B, S, KH, G, hd = 2, 512, 2, 2, 32
     q = _rand(0, (B, KH * G, hd), jnp.float32)
     k = _rand(1, (B, S, KH, hd), jnp.float32)
     v = _rand(2, (B, S, KH, hd), jnp.float32)
     lengths = jnp.asarray([9, 70], jnp.int32)
-    full = ops.decode_attention(q, k, v, lengths, block_s=64)
-    trunc = ops.decode_attention(q, k, v, lengths, block_s=64, max_len=70)
-    np.testing.assert_allclose(np.asarray(trunc), np.asarray(full),
-                               rtol=2e-5, atol=2e-5)
+    live_blocks = -(-lengths // 64) * 64
+    dead = jnp.arange(S)[None, :, None, None] >= live_blocks[:, None,
+                                                              None, None]
+    trunc = ops.decode_attention(q, jnp.where(dead, jnp.nan, k),
+                                 jnp.where(dead, jnp.nan, v), lengths,
+                                 block_s=64)
     r = ref.decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(trunc), np.asarray(r),
                                rtol=2e-5, atol=2e-5)

@@ -974,6 +974,37 @@ def test_spec_single_host_transfer_per_step():
     assert eng.runner.decode_transfers == eng.steps_run
 
 
+def test_paged_kernel_decode_is_one_program_at_any_live_length():
+    """With the paged kernel (forced through ``use_pallas``: interpret
+    mode off the chip) the decode program reads the live lengths at run
+    time: decoding at two quite different live lengths compiles it once.
+    The runner counts the kernel's calls and the live pool blocks they
+    stream, from the positions."""
+    cfg = _spec_pt_cfg().replace(use_pallas=True)
+    params = steps_lib.model_fns(cfg)["init"](jax.random.PRNGKey(0), cfg)
+    eng = Engine(cfg, params, max_slots=3, max_seq_len=64, block_size=8,
+                 num_blocks=24)
+    r = eng.runner
+    assert r.decode_attn == "paged_kernel"
+    S = r.max_slots
+    z = np.zeros((S,), np.int32)
+    seeds, temps = np.zeros((S,), np.uint32), np.zeros((S,), np.float32)
+    steps = [([3, 0, 0], [True, False, False]),
+             ([50, 20, 63], [True, True, False])]
+    for pos, active in steps:
+        r.decode(z, np.asarray(pos, np.int32), np.asarray(active), seeds,
+                 z, temps, z, np.ones((S,), np.float32),
+                 np.full((S,), -1, np.int32), z + 8)
+    assert r._decode._cache_size() == 1
+    assert r.decode_kernel_calls == 2
+    # ceil((pos + 1) / 8) over the active rows: 1, then 7 + 3
+    assert r.decode_live_blocks == 1 + 7 + 3
+    # the jnp path on this backend gathers, and says so
+    eng = Engine(_spec_pt_cfg(), params, max_slots=3, max_seq_len=64,
+                 block_size=8, num_blocks=24)
+    assert eng.runner.decode_attn == "gather"
+
+
 def test_attention_chunk_kv_max_len_parity():
     """Truncating the verify gather to the live prefix must not change
     the attention output (dropped columns are causally masked and

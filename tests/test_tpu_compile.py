@@ -51,9 +51,28 @@ def _paged(kh):
              ((B,), jnp.int32)])
 
 
+def _pt_tracks(*a):
+    """The pt-6b-d4 cell's call: 8 tracks of 1 KV head under the track
+    vmap, folded into one kernel call of 8 KV heads."""
+    q, k, v, table, lengths = a
+    return jax.vmap(lambda q, k, v: da.paged_decode_attention(
+        q, k, v, table, lengths, interpret=False))(q, k, v)
+
+
 CASES = {
     "paged_decode_attention_kh1": _paged(1),
     "paged_decode_attention_kh8": _paged(8),
+    # the cells' shapes: 160 blocks of 16 a slot; 12 pt-6b-d4 slots,
+    # 7 dense-6b slots
+    "paged_decode_attention_pt_cell": (
+        _pt_tracks,
+        [((8, 12, G, HD), BF), ((8, 641, BS, 1, HD), BF),
+         ((8, 641, BS, 1, HD), BF), ((12, 160), jnp.int32),
+         ((12,), jnp.int32)]),
+    "paged_decode_attention_dense_cell": (
+        lambda *a: da.paged_decode_attention(*a, interpret=False),
+        [((7, 8 * G, HD), BF), ((353, BS, 8, HD), BF),
+         ((353, BS, 8, HD), BF), ((7, 160), jnp.int32), ((7,), jnp.int32)]),
     "decode_attention": (
         lambda *a: da.decode_attention(*a, interpret=False),
         [((B, G, HD), BF), ((B, NMAX * BS, 1, HD), BF),
@@ -79,3 +98,49 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(kernel).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, name
+
+
+def test_pt_decode_step_streams_live_kv_on_v5e(one_chip, monkeypatch):
+    """pt-6b-d4's decode program at the cell's slots and pool (one track
+    block of 4 layers), compiled as the chip's backend selects it: the
+    paged kernel reads the pools, no [12, 2560, ...] view of every
+    slot's capacity is gathered, and no layer's pool is relaid out."""
+    import re
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch import steps as steps_lib
+    from repro.serving.engine import ModelRunner
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = get_config("pt-6b-d4").replace(n_layers=4)
+    fns = steps_lib.model_fns(cfg)
+    p_shape = jax.eval_shape(lambda k: fns["init"](k, cfg),
+                             jax.random.PRNGKey(0))
+    small, blocks = 6, 641
+    r = ModelRunner(cfg, p_shape, max_slots=12, max_seq_len=2560,
+                    block_size=BS, num_blocks=small, prefill_chunk=512)
+    assert r.decode_attn == "paged_kernel"
+
+    def spec(x, resize=False):
+        shape = tuple(blocks if resize and d == small + 1 else d
+                      for d in x.shape)
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
+
+    cache = jax.tree_util.tree_map(lambda x: spec(x, True), r.cache)
+    params = jax.tree_util.tree_map(spec, p_shape)
+    i32 = jnp.int32
+
+    def s(dt, *shape):
+        return jax.ShapeDtypeStruct(shape or (12,), dt, sharding=one_chip)
+
+    text = r._decode.lower(
+        params, cache, s(i32), s(i32), s(jnp.bool_), s(i32, 12, 160),
+        s(jnp.uint32), s(i32), s(F32), s(i32), s(F32), s(i32),
+        s(i32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\[12,2560,", text)
+    pool_copies = re.findall(r"= bf16\[\d+,\d+,8,641,16,1,128\]\S* copy\(",
+                             text)
+    # the scan writes its new cache, then one copy of each pool into the
+    # program's output: nothing per layer
+    assert len(pool_copies) <= 2, pool_copies

@@ -183,6 +183,15 @@ def test_spans_of_one_request_share_its_rid(traced):
     assert all(st["padded"] == 8 * st["rows"] for st in prefills)
 
 
+def test_decode_launch_names_its_attention_path(traced):
+    """Each decode call's ``serve.launch`` names how attention read the
+    pool: on this backend, with ``use_pallas`` off, the gather."""
+    events, _ = traced
+    paths = [st.get("attn") for n, _, _, st in events
+             if n == "serve.launch" and "attn" in st]
+    assert paths and set(paths) == {"gather"}
+
+
 def _program_text(eng, kind):
     """The lowered decode or chunk program, with op locations."""
     r = eng.runner
